@@ -28,10 +28,12 @@ class _Bracket:
             self.milestones.append(int(t))
             t *= rf
         self.milestones.append(int(max_t))
-        # rung -> list of recorded scores (higher better)
-        self.rungs: Dict[int, List[float]] = {m: [] for m in self.milestones}
+        # rung -> {trial_id: score} (higher better).  A trial is judged at a
+        # rung once, on arrival; its later results are not re-judged there
+        # against its own earlier score.
+        self.rungs: Dict[int, Dict[str, float]] = {m: {} for m in self.milestones}
 
-    def on_result(self, iteration: int, score: float
+    def on_result(self, trial_id: str, iteration: int, score: float
                   ) -> Tuple[SchedulerDecision, Optional[Dict[str, Any]]]:
         """Verdict plus the rung check that produced it (None = no new rung).
 
@@ -40,34 +42,36 @@ class _Bracket:
         """
         decision = SchedulerDecision.CONTINUE
         check: Optional[Dict[str, Any]] = None
-        for milestone in self.milestones:
-            if iteration >= milestone and milestone != self.milestones[-1]:
-                recorded = self.rungs[milestone]
-                if not any(np.isclose(score, r) for r in recorded):
-                    # promotion check against results seen so far at this rung
-                    cutoff = (
-                        float(np.percentile(recorded, (1 - 1 / self.rf) * 100))
-                        if recorded
-                        else float("-inf")
-                    )
-                    rung_decision = (SchedulerDecision.STOP if score < cutoff
-                                     else SchedulerDecision.CONTINUE)
-                    if check is None or rung_decision == SchedulerDecision.STOP:
-                        check = {"milestone": milestone, "cutoff": cutoff,
-                                 "score": score, "n_rung": len(recorded),
-                                 "rf": self.rf}
-                    recorded.append(score)
-                    if score < cutoff:
-                        decision = SchedulerDecision.STOP
+        for milestone in self.milestones[:-1]:
+            recorded = self.rungs[milestone]
+            if iteration < milestone or trial_id in recorded:
+                continue
+            # promotion check against results seen so far at this rung
+            cutoff = (
+                float(np.percentile(list(recorded.values()),
+                                    (1 - 1 / self.rf) * 100))
+                if recorded
+                else float("-inf")
+            )
+            rung_decision = (SchedulerDecision.STOP if score < cutoff
+                             else SchedulerDecision.CONTINUE)
+            if check is None or rung_decision == SchedulerDecision.STOP:
+                check = {"milestone": milestone, "cutoff": cutoff,
+                         "score": score, "n_rung": len(recorded),
+                         "rf": self.rf}
+            recorded[trial_id] = score
+            if score < cutoff:
+                decision = SchedulerDecision.STOP
         return decision, check
 
     def state_dict(self) -> Dict[str, Any]:
         # rungs keyed by int milestones -> list-of-pairs for JSON round-trips
-        return {"rungs": [[m, list(v)] for m, v in self.rungs.items()]}
+        return {"rungs": [[m, [[t, v] for t, v in r.items()]]
+                          for m, r in self.rungs.items()]}
 
     def load_state_dict(self, state: Dict[str, Any]) -> None:
         for m, scores in state["rungs"]:
-            self.rungs[int(m)] = [float(s) for s in scores]
+            self.rungs[int(m)] = {str(t): float(v) for t, v in scores}
 
     def debug_string(self) -> str:
         return " | ".join(f"r={m}:n={len(v)}" for m, v in self.rungs.items())
@@ -115,7 +119,8 @@ class AsyncHyperBandScheduler(TrialScheduler):
         b_idx = self._trial_bracket.get(trial.trial_id, 0)
         bracket = self._brackets[b_idx]
         score = self._score(result.value(self.metric))
-        decision, check = bracket.on_result(result.training_iteration, score)
+        decision, check = bracket.on_result(trial.trial_id,
+                                            result.training_iteration, score)
         if check is not None:
             self._record_decision(trial.trial_id, decision,
                                   iteration=result.training_iteration,

@@ -42,16 +42,16 @@ def _kernel(q_pos_ref, k_pos_ref, q_ref, k_ref, v_ref,  # inputs
     q = q_ref[0, 0].astype(jnp.float32)          # (bq, hd)
     k = k_ref[0, 0].astype(jnp.float32)          # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)          # (bk, hd)
-    qp = q_pos_ref[0]                            # (bq,)
-    kp = k_pos_ref[0]                             # (bk,)
+    qp = q_pos_ref[0]                            # (bq, 1)
+    kp = k_pos_ref[0]                            # (1, bk)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
 
-    d = qp[:, None] - kp[None, :]
-    ok = kp[None, :] >= 0
+    d = qp - kp
+    ok = kp >= 0
     if causal:
         ok &= d >= 0
     if window is not None:
@@ -94,7 +94,10 @@ def flash_attention_pallas(
         raise ValueError(f"Sq={Sq}/Sk={Sk} must divide blocks ({block_q},{block_k})")
     n_q, n_k = Sq // block_q, Sk // block_k
 
-    # layout: (B, heads, S, hd) for blocked access
+    # layout: (B, heads, S, hd) for blocked access.  Positions go in as a
+    # (B, Sq, 1) column and a (B, 1, Sk) row: a TPU block's last two dims
+    # must tile (8, 128) or span the array, which a (1, block) slice of a
+    # (B, S) array does not once B > 1.
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -107,8 +110,8 @@ def flash_attention_pallas(
         kernel,
         grid=(B, H, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b, h, qi, ki: (b, qi)),
-            pl.BlockSpec((1, block_k), lambda b, h, qi, ki: (b, ki)),
+            pl.BlockSpec((1, block_q, 1), lambda b, h, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, qi, ki: (b, 0, ki)),
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, block_k, hd),
                          lambda b, h, qi, ki: (b, h // G, ki, 0)),
@@ -124,5 +127,5 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, hd), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
-    )(q_pos, k_pos, qt, kt, vt)
+    )(q_pos[:, :, None], k_pos[:, None, :], qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

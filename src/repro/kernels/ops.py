@@ -1,10 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Handles block-size padding (each kernel requires divisible shapes) and the
-CPU-interpret fallback: on this container ``jax.default_backend() == 'cpu'``
-so kernels execute via ``interpret=True`` (the kernel body runs exactly as it
-would on TPU, minus the tiling performance).  On TPU the same call sites lower
-to real Mosaic kernels.
+choice of execution: on TPU the call sites lower to real Mosaic kernels; on
+the CPU they run with ``interpret=True`` (the kernel body runs as it would on
+TPU, minus the tiling), which is how the tests check them.  Any other backend
+is refused rather than interpreted.
 """
 from __future__ import annotations
 
@@ -24,7 +24,12 @@ __all__ = ["flash_attention", "rwkv6_scan", "rglru_scan", "moe_router",
 
 
 def use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """False on TPU, True on the CPU; any other backend raises."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas kernels run on tpu, or interpreted on cpu; "
+                           f"the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0.0) -> Tuple[jax.Array, int]:

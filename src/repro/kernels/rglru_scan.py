@@ -30,12 +30,13 @@ def _kernel(a_ref, b_ref, h0_ref, h_ref, carry_scr, *, chunk_t: int):
     def _init():
         carry_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    a = a_ref[0].astype(jnp.float32)   # (chunk_t, block_r)
-    b = b_ref[0].astype(jnp.float32)
-
-    def row(t, carry):
-        h = a[t] * carry + b[t]
-        h_ref[0, t, :] = h.astype(h_ref.dtype)
+    def row(t, carry):                 # carry (1, block_r)
+        # Rows are read and written through the refs: Mosaic lowers a
+        # dynamic row slice of a ref, not of a loaded value.
+        a = a_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        b = b_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        h = a * carry + b
+        h_ref[0, pl.ds(t, 1), :] = h.astype(h_ref.dtype)
         return h
 
     carry_scr[...] = jax.lax.fori_loop(0, chunk_t, row, carry_scr[...])
@@ -63,11 +64,11 @@ def rglru_scan_pallas(
         in_specs=[
             pl.BlockSpec((1, chunk_t, block_r), lambda bi, ri, ti: (bi, ti, ri)),
             pl.BlockSpec((1, chunk_t, block_r), lambda bi, ri, ti: (bi, ti, ri)),
-            pl.BlockSpec((1, block_r), lambda bi, ri, ti: (bi, ri)),
+            pl.BlockSpec((1, 1, block_r), lambda bi, ri, ti: (bi, 0, ri)),
         ],
         out_specs=pl.BlockSpec((1, chunk_t, block_r),
                                lambda bi, ri, ti: (bi, ti, ri)),
         out_shape=jax.ShapeDtypeStruct((B, S, R), a.dtype),
-        scratch_shapes=[pltpu.VMEM((block_r,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_r), jnp.float32)],
         interpret=interpret,
-    )(a, b, h0)
+    )(a, b, h0[:, None, :])  # (B, 1, R): see the h0 block above

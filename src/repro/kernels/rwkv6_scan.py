@@ -38,20 +38,27 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,   # inputs
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     logw = w_ref[0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)   # (N,)
+    u = u_ref[0].astype(jnp.float32)   # (1, N)
 
-    L = r.shape[0]
-    cum = jnp.cumsum(logw, axis=0)          # (L, N) inclusive
+    L, N = r.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    # Inclusive prefix sum over time as a lower-triangular matmul: Mosaic
+    # has no cumsum.
+    cum = jax.lax.dot_general(
+        (row >= col).astype(jnp.float32), logw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                   # (L, N)
     cum_excl = cum - logw
 
     # intra-chunk: A[t,s] = sum_n r[t,n] k[s,n] exp(cum_excl[t,n] - cum[s,n]), s<t
     ratio = cum_excl[:, None, :] - cum[None, :, :]          # (L, L, N)
-    mask = (jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-            > jax.lax.broadcasted_iota(jnp.int32, (L, L), 1))
-    ratio = jnp.where(mask[:, :, None], ratio, -jnp.inf)
+    past = (jax.lax.broadcasted_iota(jnp.int32, (L, L, N), 0)
+            > jax.lax.broadcasted_iota(jnp.int32, (L, L, N), 1))
+    ratio = jnp.where(past, ratio, -jnp.inf)
     A = jnp.sum(r[:, None, :] * k[None, :, :] * jnp.exp(ratio), axis=-1)  # (L, L)
-    diag = jnp.sum(r * u[None, :] * k, axis=-1)              # (L,)
-    A = A + jnp.diag(diag)
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)        # (L, 1)
+    A = A + jnp.where(row == col, diag, 0.0)
 
     y_intra = jax.lax.dot_general(A, v, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
@@ -60,9 +67,17 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,   # inputs
                                   preferred_element_type=jnp.float32)
     y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
 
-    decay_all = jnp.exp(cum[-1])                              # (N,)
-    k_scaled = k * jnp.exp(cum[-1][None, :] - cum)            # (L, N)
-    s_scr[...] = decay_all[:, None] * s_scr[...] + jax.lax.dot_general(
+    last = cum[L - 1:L]                                       # (1, N)
+    k_scaled = k * jnp.exp(last - cum)                        # (L, N)
+    # diag(exp(last)) @ S scales the state's rows; as a matmul it needs no
+    # (1, N) -> (N, 1) relayout.
+    n_row = jax.lax.broadcasted_iota(jnp.int32, (N, N), 0)
+    n_col = jax.lax.broadcasted_iota(jnp.int32, (N, N), 1)
+    decay = jnp.where(n_row == n_col, jnp.exp(last), 0.0)     # (N, N)
+    s_scr[...] = jax.lax.dot_general(
+        decay, s_scr[...], (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32) + jax.lax.dot_general(
         k_scaled, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
@@ -91,7 +106,6 @@ def rwkv6_scan_pallas(
 
     rb, kb, vb = to_bh(r), to_bh(k), to_bh(v)
     wb = to_bh(logw.astype(jnp.float32))
-    ub = jnp.tile(u, (B, 1))                         # (B*H, N)
     s0 = state.reshape(B * H, N, N).astype(jnp.float32)
 
     kernel = functools.partial(_kernel, n_chunks=n_chunks, chunk=chunk)
@@ -103,7 +117,7 @@ def rwkv6_scan_pallas(
             pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, N), lambda bh, ci: (bh, 0)),
+            pl.BlockSpec((1, 1, N), lambda bh, ci: (bh % H, 0, 0)),
             pl.BlockSpec((1, N, N), lambda bh, ci: (bh, 0, 0)),
         ],
         out_specs=[
@@ -116,6 +130,6 @@ def rwkv6_scan_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         interpret=interpret,
-    )(rb, kb, vb, wb, ub, s0)
+    )(rb, kb, vb, wb, u[:, None, :], s0)
     return (y.reshape(B, H, S, N).transpose(0, 2, 1, 3),
             s_out.reshape(B, H, N, N))

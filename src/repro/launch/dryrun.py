@@ -32,7 +32,7 @@ from ..dist.sharding import (batch_specs, cache_specs, make_shardings,
                              param_specs, train_state_specs)
 from ..models import ModelConfig, decode_step, forward_encode, init_params, prefill
 from ..train import adamw, linear_warmup_cosine, make_train_state, make_train_step
-from .mesh import HW, make_production_mesh
+from .mesh import V5E, make_production_mesh
 from .roofline import analyze
 from .shapes import SHAPES, ShapeSpec, dryrun_config, input_specs, skip_reason
 
@@ -141,10 +141,11 @@ def lower_one(
     compiled = lowered.compile()
     t_compile = time.time() - t0
 
+    # The placeholder devices are CPUs; the program stands for a v5e pod.
     report = analyze(
         arch, shape.name, mesh_name, int(chips), compiled,
         n_params_active=active_param_count(cfg), n_tokens=n_tokens,
-        kind=shape.kind)
+        kind=shape.kind, device_kind=V5E)
     record.update(status="compiled", t_compile_s=round(t_compile, 2),
                   **report.to_dict())
 
@@ -153,8 +154,7 @@ def lower_one(
         print(f"[dryrun] {arch} x {shape.name} x {mesh_name} "
               f"(lower {t_lower:.1f}s, compile {t_compile:.1f}s)")
         print(f"  memory_analysis: {ma}")
-        from .roofline import normalize_cost_analysis
-        ca = normalize_cost_analysis(compiled.cost_analysis())
+        ca = compiled.cost_analysis() or {}
         print(f"  cost_analysis: flops={ca.get('flops', 0):.3e} "
               f"bytes={ca.get('bytes accessed', 0):.3e}")
         print(f"  roofline: compute={report.compute_s*1e3:.2f}ms "

@@ -1,4 +1,4 @@
-"""Production mesh builders.
+"""Production mesh builders and per-chip peak rates.
 
 Functions (not module constants) so importing never touches jax device state.
 Target: TPU v5e, 256 chips/pod; single-pod (16, 16) = (data, model), multi-pod
@@ -6,30 +6,47 @@ Target: TPU v5e, 256 chips/pod; single-pod (16, 16) = (data, model), multi-pod
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from dataclasses import dataclass
+from typing import Tuple
 
 import jax
 
-__all__ = ["make_production_mesh", "make_mesh", "HW"]
+__all__ = ["make_production_mesh", "make_mesh", "HW", "V5E", "ChipPeaks",
+           "peaks"]
 
 
-class HW:
-    """TPU v5e hardware constants used by the roofline analysis."""
-    PEAK_FLOPS_BF16 = 197e12       # per chip
-    HBM_BW = 819e9                 # bytes/s per chip
-    ICI_BW = 50e9                  # bytes/s per link
-    HBM_BYTES = 16 * 2**30         # 16 GiB per chip
-    CHIPS_PER_POD = 256
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published peak rates of one chip, the denominators of the roofline."""
+    flops_bf16: float   # FLOP/s
+    hbm_bw: float       # HBM bytes/s
+    hbm_bytes: int      # HBM capacity
+    ici_bw: float       # bytes/s per inter-chip link
+
+
+V5E = "TPU v5 lite"  # jax.Device.device_kind of a TPU v5e chip
+
+# Keyed by ``jax.Device.device_kind``.  Source: Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16, 16 GiB of HBM at 819 GB/s, 1,600 Gbit/s of
+# inter-chip interconnect per chip, i.e. 50 GB/s on each of its 4 links.
+HW = {
+    V5E: ChipPeaks(flops_bf16=197e12, hbm_bw=819e9, hbm_bytes=16 * 2**30,
+                   ici_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """Peak rates of ``device_kind``; a device not in ``HW`` is an error."""
+    try:
+        return HW[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak rates for device kind {device_kind!r} "
+                         f"(known: {sorted(HW)})") from None
 
 
 def _make(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    # Auto axis types are the default on old jax and an explicit kwarg on new;
-    # pass them only where supported so both jax 0.4.x and 0.5+ work.
-    try:
-        axis_type = jax.sharding.AxisType.Auto  # jax >= 0.5
-    except AttributeError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -4,6 +4,9 @@
   memory term     = HLO_bytes / (chips x HBM_bw)
   collective term = collective_bytes / (chips x link_bw)
 
+The peaks are those of the analysed device kind (``launch.mesh.HW``); a
+kind with no entry there is an error, not a default.
+
 Methodology.  ``compiled.cost_analysis()`` reports per-device numbers but
 counts ``while`` bodies ONCE (verified empirically: a scanned L-layer stack
 reports 1/L of the flops), so we parse the compiled HLO text ourselves and
@@ -30,18 +33,9 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
-from .mesh import HW
+from .mesh import ChipPeaks, peaks
 
-__all__ = ["RooflineReport", "analyze", "hlo_costs", "model_flops",
-           "normalize_cost_analysis"]
-
-
-def normalize_cost_analysis(ca) -> dict:
-    """``compiled.cost_analysis()`` returns a dict on jax>=0.5, a [dict] on
-    0.4.x, and None on some backends; normalize all three to a dict."""
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    return ca or {}
+__all__ = ["RooflineReport", "analyze", "hlo_costs", "model_flops"]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -225,6 +219,7 @@ class RooflineReport:
     shape: str
     mesh: str
     chips: int
+    device_kind: str             # key into launch.mesh.HW
     # per-device, trip-weighted, from the HLO walk
     device_flops: float          # dot flops
     device_bytes: float          # traffic proxy
@@ -242,16 +237,20 @@ class RooflineReport:
     n_tokens: int
 
     @property
+    def peaks(self) -> ChipPeaks:
+        return peaks(self.device_kind)
+
+    @property
     def compute_s(self) -> float:
-        return self.device_flops / HW.PEAK_FLOPS_BF16
+        return self.device_flops / self.peaks.flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.device_bytes / HW.HBM_BW
+        return self.device_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / HW.ICI_BW
+        return self.collective_bytes / self.peaks.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -277,7 +276,7 @@ class RooflineReport:
     def to_dict(self) -> Dict[str, Any]:
         return {
             "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
-            "chips": self.chips,
+            "chips": self.chips, "device_kind": self.device_kind,
             "device_flops": self.device_flops,
             "device_bytes": self.device_bytes,
             "collective_bytes": self.collective_bytes,
@@ -298,9 +297,12 @@ class RooflineReport:
 def analyze(
     arch: str, shape_name: str, mesh_name: str, chips: int,
     compiled, n_params_active: int, n_tokens: int, kind: str,
-    hlo_text: Optional[str] = None,
+    device_kind: str, hlo_text: Optional[str] = None,
 ) -> RooflineReport:
-    ca = normalize_cost_analysis(compiled.cost_analysis())
+    """``device_kind`` names the chip the program was compiled for; its
+    peaks must be in ``launch.mesh.HW``."""
+    peaks(device_kind)  # fail before the HLO walk, not when a term is read
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     text = hlo_text if hlo_text is not None else compiled.as_text()
     costs = hlo_costs(text)
@@ -308,7 +310,7 @@ def analyze(
                if k.startswith("coll:")}
     return RooflineReport(
         arch=arch, shape=shape_name, mesh=mesh_name, chips=chips,
-        device_flops=float(costs.get("dot_flops", 0.0)),
+        device_kind=device_kind, device_flops=float(costs.get("dot_flops", 0.0)),
         device_bytes=float(costs.get("traffic_bytes", 0.0)),
         collective_bytes=float(costs.get("collective_bytes", 0.0)),
         collectives_by_kind=by_kind,

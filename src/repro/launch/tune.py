@@ -6,12 +6,19 @@
 
 Runs a Tune experiment over a model's optimizer hyperparameters with any of
 the six built-in schedulers, optionally driven by a searcher (TPE/random),
-with trials placed on mesh slices via the SlicePool.  ``--executor`` picks the
-execution tier: ``serial`` (host time-slicing), ``concurrent`` (one worker
-thread per trial, overlapped JAX dispatch across disjoint slices, heartbeat
-straggler detection), ``process`` (one spawned worker *process* per trial —
-GIL-free host stepping, checkpoint bytes over the ObjectStore spill surface,
-and kill-on-straggle reclamation after ``--straggler-deadline`` seconds),
+with trials placed on mesh slices via the SlicePool.  By default the pool
+holds this host's devices and each trial takes one (``--devices-per-trial``);
+``--total-devices N`` swaps in a virtual pool of N devices for rehearsals on
+the CPU.  The process and cluster tiers always place on virtual pools, and
+the launcher then leaves the devices to the worker processes.  The exit code
+is non-zero when no trial produced a result or any trial ended in ERROR.
+
+``--executor`` picks the execution tier: ``serial`` (host time-slicing),
+``concurrent`` (one worker thread per trial, overlapped JAX dispatch across
+disjoint slices, heartbeat straggler detection), ``process`` (one spawned
+worker *process* per trial — GIL-free host stepping, checkpoint bytes over
+the ObjectStore spill surface, and kill-on-straggle reclamation after
+``--straggler-deadline`` seconds),
 ``cluster`` (worker processes scheduled across a roster of hosts over the
 length-prefixed socket transport — per-host SlicePools, host heartbeats,
 content-addressed checkpoint fetch, host eviction; DESIGN.md §11), or
@@ -86,19 +93,26 @@ from __future__ import annotations
 import argparse
 import json
 
+import jax
+
 from ..configs import get_config, list_archs
 from ..core import (ASHAScheduler, FIFOScheduler, GPSearcher,
                     HyperBandScheduler, MedianStoppingRule,
                     PopulationBasedTraining, Resources, TPESearcher,
-                    RandomSearcher, loguniform, run_experiments, uniform)
+                    RandomSearcher, TrialStatus, loguniform, run_experiments,
+                    uniform)
 from ..dist.submesh import SlicePool
 from ..train.trainable import make_model_trainable, model_trainable_factory
+from .compile_cache import setup_compile_cache
+
+# Capacity of the virtual pool of the process and cluster tiers when
+# --total-devices is not given: they must not touch the chips themselves.
+_VIRTUAL_DEVICES = 256
 
 
-def build_vmap_executor(cfg, args):
+def build_vmap_executor(cfg, args, total_devices: int):
     """Model selection as one SPMD program: N lanes of the same tiny LM,
     vmapped over (lr, weight_decay) with momentum SGD (see bench_vmap.py)."""
-    import jax
     import jax.numpy as jnp
 
     from ..core import CheckpointManager, ObjectStore
@@ -134,7 +148,7 @@ def build_vmap_executor(cfg, args):
                                steps_per_iter=args.steps_per_iter)
     return VmapExecutor(spec, CheckpointManager(ObjectStore()),
                         n_lanes=min(args.num_samples, 8),
-                        total_devices=args.total_devices)
+                        total_devices=total_devices)
 
 
 def build_scheduler(name: str, max_iters: int):
@@ -168,8 +182,10 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=64)
     ap.add_argument("--steps-per-iter", type=int, default=3)
-    ap.add_argument("--devices-per-trial", type=int, default=8)
-    ap.add_argument("--total-devices", type=int, default=256)
+    ap.add_argument("--devices-per-trial", type=int, default=1)
+    ap.add_argument("--total-devices", type=int, default=None,
+                    help="a virtual pool of N devices (CPU rehearsals); "
+                         "unset, the pool is this host's devices")
     ap.add_argument("--executor", default="serial",
                     choices=["serial", "concurrent", "process", "cluster",
                              "vmap"])
@@ -249,6 +265,7 @@ def main() -> None:
     if args.resume and not args.log_dir:
         ap.error("--resume requires --log-dir (the run's artifacts live there)")
 
+    setup_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -275,15 +292,19 @@ def main() -> None:
         searcher = RandomSearcher(space, metric="loss", mode="min",
                                   max_trials=args.num_samples, seed=args.seed)
 
-    if args.executor == "vmap":
-        executor = build_vmap_executor(cfg, args)
-        pool = None  # lanes replace slices; placement is the stacked program's
-    elif args.executor == "cluster":
-        executor = args.executor
-        pool = None  # per-host pools: the roster is the capacity
+    if args.executor in ("process", "cluster"):
+        # Worker processes own the devices; the launcher keeps off them.
+        total = args.total_devices or _VIRTUAL_DEVICES
+        # cluster: per-host pools, the roster is the capacity
+        pool = SlicePool(n_virtual=total) if args.executor == "process" else None
     else:
-        executor = args.executor
-        pool = SlicePool(n_virtual=args.total_devices)
+        pool = (SlicePool(devices=jax.devices()) if args.total_devices is None
+                else SlicePool(n_virtual=args.total_devices))
+        total = pool.n_total
+    executor = args.executor
+    if args.executor == "vmap":
+        executor = build_vmap_executor(cfg, args, total)
+        pool = None  # lanes replace slices; placement is the stacked program's
     analysis = run_experiments(
         trainable,
         None if searcher else space,
@@ -292,7 +313,7 @@ def main() -> None:
         num_samples=args.num_samples if not searcher else 1,
         stop={"training_iteration": args.max_iters},
         resources_per_trial=Resources(cpu=1, devices=args.devices_per_trial),
-        total_devices=args.total_devices,
+        total_devices=total,
         slice_pool=pool,
         executor=executor,
         hosts=args.hosts if args.executor == "cluster" else None,
@@ -326,10 +347,15 @@ def main() -> None:
     if analysis.best_value() is None:
         print("[tune] no trial produced a result (check that "
               "--devices-per-trial fits --total-devices)")
-        return
+        raise SystemExit(1)
     print(f"[tune] best config: {json.dumps({k: v for k, v in analysis.best_config().items() if isinstance(v, (int, float, str))})}")
     print(f"[tune] best loss:   {analysis.best_value():.4f}")
     print(f"[tune] total training iterations across trials: {analysis.total_iterations()}")
+    errored = [t.trial_id for t in analysis.trials
+               if t.status == TrialStatus.ERROR]
+    if errored:
+        print(f"[tune] {len(errored)} trial(s) ended in ERROR: {errored}")
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

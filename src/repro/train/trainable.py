@@ -12,11 +12,11 @@ import time
 from typing import Any, Dict, Optional
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from ..core.api import Trainable
 from ..data.pipeline import DataConfig, SyntheticLMDataset
+from ..launch.mesh import HW
+from ..launch.roofline import analyze
 from ..models import ModelConfig, param_count
 from .optimizer import adamw, linear_warmup_cosine, sgd
 from .train_step import TrainState, make_train_state, make_train_step
@@ -41,6 +41,18 @@ def _build_optimizer(hp: Dict[str, Any], total_steps: int):
     raise ValueError(f"unknown optimizer {name!r}")
 
 
+def _slice_device(sl: Any) -> Optional[jax.Device]:
+    """The device a trial on slice ``sl`` runs on; None for the default."""
+    if sl is None or sl.devices is None:
+        return None
+    if sl.size != 1:
+        raise NotImplementedError(
+            f"a model trial on a slice of {sl.size} real devices needs its "
+            "step sharded over the slice (ROADMAP 2.1); ModelTrainable "
+            "places a trial on one device")
+    return sl.devices[0]
+
+
 class ModelTrainable(Trainable):
     """config keys: model_cfg (ModelConfig), lr/warmup/optimizer/... (hypers),
     batch/seq_len/steps_per_iter/total_steps/data_seed (workload).
@@ -52,7 +64,13 @@ class ModelTrainable(Trainable):
     roofline tag from ``launch/roofline.py``.  The runner pops it off the
     metric stream and publishes it as trial metadata (``trial.profile``) plus
     a PROFILE event, so it rides the existing result transport across all
-    executor tiers.  Disable with ``profile=False``."""
+    executor tiers.  Disable with ``profile=False``.
+
+    Placement: on a slice of real devices (``config["_slice"]`` from a
+    device-mode ``SlicePool``) the state, batches and step live on the
+    slice's device; a virtual slice, or none, leaves them on the default
+    device.  A trial spans one device only until the step is sharded over its
+    slice (ROADMAP 2.1), so a wider real slice is refused."""
 
     def setup(self, config: Dict[str, Any]) -> None:
         self.model_cfg: ModelConfig = config["model_cfg"]
@@ -65,6 +83,7 @@ class ModelTrainable(Trainable):
             vocab_size=self.model_cfg.vocab_size,
             seed=int(config.get("data_seed", 0))))
         self._global_step = 0
+        self._device = _slice_device(config.get("_slice"))
         self._build(config)
 
     def _build(self, hp: Dict[str, Any]) -> None:
@@ -72,7 +91,10 @@ class ModelTrainable(Trainable):
         raw_step = make_train_step(self.model_cfg, self._opt,
                                    microbatch=int(hp.get("microbatch", 0)))
         seed = int(hp.get("init_seed", 0))
-        self.state = make_train_state(jax.random.key(seed), self.model_cfg, self._opt)
+        with jax.default_device(self._device):
+            state = make_train_state(jax.random.key(seed), self.model_cfg,
+                                     self._opt)
+        self.state = jax.device_put(state, self._device)
         self._pending_profile = bool(hp.get("profile", True))
         self._compiled = None
         self._compile_s: Optional[float] = None
@@ -82,8 +104,7 @@ class ModelTrainable(Trainable):
             # hands the roofline walk the post-fusion HLO it needs — a
             # traced-only jit exposes StableHLO, which the cost regexes
             # cannot parse.
-            batch = {k: jnp.asarray(v)
-                     for k, v in self._data.batch_at(self._global_step).items()}
+            batch = self._batch(self._global_step)
             p0 = time.perf_counter()
             self._compiled = jax.jit(raw_step).lower(self.state, batch).compile()
             self._compile_s = time.perf_counter() - p0
@@ -91,13 +112,15 @@ class ModelTrainable(Trainable):
         else:
             self._step_fn = jax.jit(raw_step)
 
+    def _batch(self, step: int) -> Dict[str, jax.Array]:
+        return jax.device_put(self._data.batch_at(step), self._device)
+
     # -- narrow-waist contract ---------------------------------------------------
     def step(self) -> Dict[str, Any]:
         t0 = time.time()
         step_times = [] if self._pending_profile else None
         for _ in range(self.steps_per_iter):
-            batch = {k: jnp.asarray(v)
-                     for k, v in self._data.batch_at(self._global_step).items()}
+            batch = self._batch(self._global_step)
             if step_times is None:
                 self.state, metrics = self._step_fn(self.state, batch)
             else:
@@ -125,6 +148,9 @@ class ModelTrainable(Trainable):
     def _make_profile(self, step_times) -> Dict[str, Any]:
         first = step_times[0]
         steady = min(step_times[1:]) if len(step_times) > 1 else first
+        params = jax.tree_util.tree_leaves(self.state.params)
+        devices = sorted({d for x in params for d in x.devices()},
+                         key=lambda d: d.id)
         prof: Dict[str, Any] = {
             "first_step_s": round(first, 6),
             "steady_step_s": round(steady, 6),
@@ -135,31 +161,25 @@ class ModelTrainable(Trainable):
             "param_count": int(param_count(self.state.params)),
             "batch": self.batch,
             "seq_len": self.seq_len,
+            "devices": [f"{d.platform}:{d.id}" for d in devices],
         }
-        try:
-            stats = jax.local_devices()[0].memory_stats() or {}
-            if "bytes_in_use" in stats:
-                prof["device_bytes_in_use"] = int(stats["bytes_in_use"])
-        except Exception:
-            pass  # memory_stats is backend-dependent (absent on CPU)
+        dev = devices[0]
+        stats = dev.memory_stats() or {}  # None on the CPU backend
+        for key in ("bytes_in_use", "peak_bytes_in_use"):
+            if key in stats:
+                prof[f"device_{key}"] = int(stats[key])
         if self._compiled is not None:
-            try:
-                ma = self._compiled.memory_analysis()
-                for key, attr in (("arg_bytes", "argument_size_in_bytes"),
-                                  ("temp_bytes", "temp_size_in_bytes"),
-                                  ("output_bytes", "output_size_in_bytes")):
-                    v = getattr(ma, attr, None)
-                    if v is not None:
-                        prof[key] = int(v)
-            except Exception:
-                pass
-            try:
-                from ..launch.roofline import analyze
+            ma = self._compiled.memory_analysis()
+            prof["arg_bytes"] = int(ma.argument_size_in_bytes)
+            prof["temp_bytes"] = int(ma.temp_size_in_bytes)
+            prof["output_bytes"] = int(ma.output_size_in_bytes)
+            if dev.device_kind in HW:  # no peaks, no roofline (e.g. the CPU)
                 rep = analyze(
                     arch=self.model_cfg.arch_id, shape_name="trial",
                     mesh_name="local", chips=1, compiled=self._compiled,
                     n_params_active=int(param_count(self.state.params)),
-                    n_tokens=self.batch * self.seq_len, kind="train")
+                    n_tokens=self.batch * self.seq_len, kind="train",
+                    device_kind=dev.device_kind)
                 prof["predicted_step_s"] = round(rep.step_time_s, 6)
                 prof["dominant"] = rep.dominant
                 prof["roofline_compute_s"] = round(rep.compute_s, 6)
@@ -168,8 +188,6 @@ class ModelTrainable(Trainable):
                 if rep.step_time_s > 0:
                     prof["achieved_vs_predicted"] = round(
                         steady / rep.step_time_s, 4)
-            except Exception:
-                pass  # roofline is best-effort decoration, never a crash
         return prof
 
     def save(self) -> Any:
@@ -179,9 +197,7 @@ class ModelTrainable(Trainable):
         }
 
     def restore(self, snapshot: Any) -> None:
-        st = snapshot["state"]
-        as_jnp = jax.tree_util.tree_map(jnp.asarray, st)
-        state = TrainState(**as_jnp)
+        state = TrainState(**jax.device_put(snapshot["state"], self._device))
         # A PBT mutation may have switched optimizer family: if the donor's
         # opt_state tree doesn't match this trainable's optimizer, re-init it
         # (params are what cloning is about; moments restart harmlessly).

@@ -9,7 +9,8 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from _hypothesis_stub import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro.core import (ASHAScheduler, CheckpointManager, EventType,
                         FIFOScheduler, FairShare, GreedyFill,
                         HyperBandScheduler, Logger, MedianStoppingRule,
@@ -122,9 +123,8 @@ class TestSlicePoolResize:
         assert pool.can_resize(b, 2) and pool.can_resize(b, 8)
         assert not pool.can_resize(b, 12)
 
-    # -- acquire/release/resize walk: property-based (hypothesis), with a
-    # seeded fallback so the invariant keeps running where hypothesis is
-    # absent (tests/_hypothesis_stub.py skips the @given test there).
+    # -- acquire/release/resize walk: property-based (hypothesis), plus a
+    # seeded walk that pins a fixed set of scripts.
 
     @staticmethod
     def _run_walk(pool_size, ops):
@@ -157,7 +157,6 @@ class TestSlicePoolResize:
             pool.release(h)
         assert pool.n_free == pool_size and pool.fragments() == 0
 
-    @pytest.mark.skipif(not HAVE_HYPOTHESIS, reason="hypothesis not installed")
     @settings(max_examples=200, deadline=None)
     @given(ops=st.lists(
         st.tuples(st.integers(min_value=0, max_value=2),

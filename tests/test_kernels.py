@@ -8,7 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
 
@@ -17,6 +18,17 @@ KEY = jax.random.key(0)
 
 def randn(i, shape, dtype=jnp.float32, scale=1.0):
     return (jax.random.normal(jax.random.fold_in(KEY, i), shape) * scale).astype(dtype)
+
+
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_use_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="interpreted on cpu"):
+            ops.use_interpret()
+    else:
+        assert ops.use_interpret() is interpret
 
 
 class TestFlashAttention:

@@ -1,11 +1,11 @@
 """Launch-layer coverage: shape specs, applicability matrix, input structs,
-active-param accounting, mesh constants."""
+active-param accounting, per-chip peaks, compile cache."""
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config, list_archs
-from repro.launch.mesh import HW
+from repro.launch.mesh import HW, V5E, peaks
 from repro.launch.shapes import (SHAPES, applicable, dryrun_config, input_specs,
                                  skip_reason)
 
@@ -113,7 +113,67 @@ class TestActiveParams:
 
 class TestHW:
     def test_v5e_constants(self):
-        assert HW.PEAK_FLOPS_BF16 == 197e12
-        assert HW.HBM_BW == 819e9
-        assert HW.ICI_BW == 50e9
-        assert HW.CHIPS_PER_POD == 256
+        v5e = HW[V5E]
+        assert v5e.flops_bf16 == 197e12
+        assert v5e.hbm_bw == 819e9
+        assert v5e.hbm_bytes == 16 * 2**30
+        assert v5e.ici_bw == 50e9
+        assert peaks(V5E) is v5e
+
+    def test_unknown_device_kind_is_an_error(self):
+        with pytest.raises(ValueError, match="no peak rates"):
+            peaks(jax.devices()[0].device_kind)  # the CPU: not a chip
+
+
+_CACHE_PROBE = r"""
+import jax
+from repro.launch.compile_cache import DEFAULT_CACHE_DIR, setup_compile_cache
+print(setup_compile_cache() == (jax.config.jax_compilation_cache_dir or ""),
+      setup_compile_cache() == DEFAULT_CACHE_DIR)
+"""
+
+
+class TestCompileCache:
+    """In a child process: the cache directory is global JAX state."""
+
+    @pytest.mark.parametrize("env_dir,expect", [
+        (None, "True True"), ("/nonexistent/jax-cache", "True False")])
+    def test_cache_dir(self, env_dir, expect):
+        import os
+        import subprocess
+        import sys
+        env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if env_dir:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                             cwd=root, capture_output=True, text=True,
+                             timeout=120)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.strip() == expect
+
+    def test_import_leaves_cache_off(self):
+        import repro.launch.compile_cache  # noqa: F401
+        import repro.launch.tune  # noqa: F401
+        assert jax.config.jax_compilation_cache_dir is None
+
+
+class TestTuneLauncher:
+    @pytest.mark.parametrize("pool_args", [[], ["--total-devices", "1"]],
+                             ids=["host-devices", "virtual"])
+    def test_exit_nonzero_without_results(self, pool_args, tmp_path):
+        """A trial wider than the pool never runs: the launcher must fail."""
+        import os
+        import subprocess
+        import sys
+        env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        out = subprocess.run(
+            [sys.executable, "-m", "repro.launch.tune", "--reduced",
+             "--num-samples", "1", "--max-iters", "1", "--batch", "2",
+             "--seq-len", "16", "--devices-per-trial", "2", *pool_args],
+            env=env, cwd=root, capture_output=True, text=True, timeout=300)
+        assert out.returncode == 1, out.stdout + out.stderr
+        assert "no trial produced a result" in out.stdout

@@ -5,7 +5,8 @@ import os
 
 import numpy as np
 import pytest
-from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (CSVLogger, ConsoleLogger, ExperimentAnalysis,
                         JSONLLogger, Result, Trial, TrialStatus)

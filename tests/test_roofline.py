@@ -77,7 +77,7 @@ class TestModelFlops:
 class TestReport:
     def _report(self, **kw):
         base = dict(arch="a", shape="s", mesh="m", chips=256,
-                    device_flops=1e12, device_bytes=1e11,
+                    device_kind="TPU v5 lite", device_flops=1e12, device_bytes=1e11,
                     collective_bytes=1e9, collectives_by_kind={},
                     ca_flops_raw=0, ca_bytes_raw=0,
                     arg_bytes=2**30, temp_bytes=2**30, output_bytes=0,
@@ -94,6 +94,10 @@ class TestReport:
         assert r.useful_flops_ratio == pytest.approx(2.56e14 / (1e12 * 256))
         assert r.hbm_per_device_gib == pytest.approx(2.0)
         assert r.step_time_s == r.memory_s
+
+    def test_unknown_device_kind_raises(self):
+        with pytest.raises(ValueError, match="no peak rates"):
+            self._report(device_kind="cpu").compute_s
 
     def test_dict_roundtrip_keys(self):
         d = self._report().to_dict()

@@ -2,7 +2,8 @@
 checkpoint serialization — the distributed-substrate invariants."""
 import numpy as np
 import pytest
-from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import jax.numpy as jnp
 

@@ -77,6 +77,34 @@ class TestASHA:
         with pytest.raises(ValueError):
             ASHAScheduler(max_t=1, grace_period=5)
 
+    def test_trial_judged_once_per_rung(self):
+        """A later, worse result of a trial is not judged again at a rung it
+        passed, where the only score to beat was its own earlier one."""
+        from repro.core import Result
+        sched = ASHAScheduler(metric="loss", mode="min", max_t=10,
+                              grace_period=1, reduction_factor=2)
+        t = Trial({"quality": 0.0}, trial_id="lone")
+        sched.on_trial_add(None, t)
+        for it, loss in enumerate([1.0, 1.1, 1.2, 1.3], start=1):
+            verdict = sched.on_result(None, t, Result("lone", it, {"loss": loss}))
+            assert verdict == SchedulerDecision.CONTINUE, (it, loss)
+        # one score per rung, whatever the number of results
+        rungs = sched._brackets[0].rungs
+        assert {m: len(r) for m, r in rungs.items()} == {1: 1, 2: 1, 4: 1, 8: 0, 10: 0}
+
+    def test_rung_state_round_trips(self):
+        from repro.core import Result
+        sched = ASHAScheduler(metric="loss", mode="min", max_t=9,
+                              grace_period=1, reduction_factor=3)
+        for i, loss in enumerate([2.0, 1.0, 3.0]):
+            t = Trial({"quality": loss}, trial_id=f"t{i}")
+            sched.on_trial_add(None, t)
+            sched.on_result(None, t, Result(t.trial_id, 1, {"loss": loss}))
+        clone = ASHAScheduler(metric="loss", mode="min", max_t=9,
+                              grace_period=1, reduction_factor=3)
+        clone.load_state_dict(sched.state_dict())
+        assert clone._brackets[0].rungs == sched._brackets[0].rungs
+
 
 class TestHyperBand:
     def test_budget_much_less_than_full(self):

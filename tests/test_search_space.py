@@ -3,7 +3,8 @@ import math
 
 import numpy as np
 import pytest
-from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.search.space import (Categorical, GridSearch, LogUniform,
                                      Normal, QRandInt, RandInt, Uniform,

@@ -3,7 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.data import DataConfig, SyntheticLMDataset
 from repro.models import ModelConfig
@@ -187,10 +188,44 @@ class TestHardwareProfile:
         assert tr.reset_config({"lr": 5e-4})  # PBT mutation path
         assert "_profile" in tr.step()
 
-    def test_roofline_tag(self):
+    def test_roofline_tag(self, monkeypatch):
+        from repro.launch import mesh
+        # The CPU has no published peaks; lend it the v5e's to check the tag.
+        monkeypatch.setitem(mesh.HW, jax.devices()[0].device_kind,
+                            mesh.HW[mesh.V5E])
         tr = self._trainable(profile_roofline=True)
         p = tr.step()["_profile"]
         assert p["predicted_step_s"] > 0
         assert p["dominant"] in ("compute", "memory", "collective")
         assert p["achieved_vs_predicted"] > 0
         assert p["arg_bytes"] > 0 and p["temp_bytes"] > 0
+
+    def test_no_roofline_without_peaks(self):
+        p = self._trainable(profile_roofline=True).step()["_profile"]
+        assert p["arg_bytes"] > 0
+        assert not any(k.startswith(("roofline_", "predicted", "achieved"))
+                       for k in p)
+        assert "dominant" not in p
+
+    def test_state_lives_on_the_slice_device(self):
+        from repro.dist.submesh import SlicePool
+        dev = jax.devices()[0]
+        tr = self._trainable(_slice=SlicePool(devices=[dev]).acquire(1))
+        p = tr.step()["_profile"]
+        assert p["devices"] == [f"{dev.platform}:{dev.id}"]
+        for x in jax.tree_util.tree_leaves(tr.state):
+            assert x.committed and x.devices() == {dev}
+        tr.restore(tr.save())
+        assert all(x.devices() == {dev}
+                   for x in jax.tree_util.tree_leaves(tr.state))
+
+    def test_wide_real_slice_is_refused(self):
+        from repro.dist.submesh import MeshSlice
+        dev = jax.devices()[0]
+        with pytest.raises(NotImplementedError, match="ROADMAP 2.1"):
+            self._trainable(_slice=MeshSlice(0, 2, (dev, dev)))
+
+    def test_virtual_slice_uses_default_device(self):
+        from repro.dist.submesh import SlicePool
+        tr = self._trainable(_slice=SlicePool(n_virtual=4).acquire(2))
+        assert all(not x.committed for x in jax.tree_util.tree_leaves(tr.state))
