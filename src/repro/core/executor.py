@@ -477,16 +477,10 @@ class SerialMeshExecutor(_SlicedExecutor):
                     pass
                 continue
             trial = self._trials[trial_id]
-            tracer = self.obs.tracer
             try:
-                if tracer.enabled:
-                    t0 = tracer.clock.time()
+                with self.obs.tracer.span("step", trial_id, cat="train") as sp:
                     metrics = trainable.train()
-                    tracer.record("step", trial_id, t0,
-                                  tracer.clock.time() - t0, cat="train",
-                                  iteration=trainable.iteration)
-                else:
-                    metrics = trainable.train()
+                    sp.arg("iteration", trainable.iteration)
             except Exception as e:  # noqa: BLE001 — trial error, not framework error
                 return trial, e
             done = bool(metrics.pop("done", False))

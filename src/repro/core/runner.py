@@ -367,18 +367,16 @@ class TrialRunner:
                 return
         tracer = self.obs.tracer
         while True:
-            t_dec = tracer.clock.time() if tracer.enabled else 0.0
-            trial = self._choose()
-            if trial is None:
-                suggested = self._maybe_suggest()
-                if suggested is None:
-                    return
+            with tracer.span("schedule.decision", cat="sched") as sp:
                 trial = self._choose()
+                if trial is None and self._maybe_suggest() is not None:
+                    trial = self._choose()
                 if trial is None:
-                    return
-            if tracer.enabled:
-                tracer.record("schedule.decision", trial.trial_id, t_dec,
-                              tracer.clock.time() - t_dec, cat="sched")
+                    sp.discard()  # no launch, no decision span
+                else:
+                    sp.set_trace(trial.trial_id)
+            if trial is None:
+                return
             checkpoint = trial.checkpoint if trial.status == TrialStatus.PAUSED else None
             restored = checkpoint is not None
             ok = self.executor.start_trial(trial, checkpoint=checkpoint)

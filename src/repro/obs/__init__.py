@@ -3,7 +3,8 @@
 One clock-injected bundle threaded through the whole stack:
 
 - ``Tracer`` (tracing.py) — per-trial spans for every lifecycle phase,
-  deterministic under a ``VirtualClock``, exported as Chrome trace-event JSON.
+  deterministic under a ``VirtualClock``, exported as Chrome trace-event JSON;
+  ``span`` opens a child of the open span from code that holds no tracer.
 - ``MetricsRegistry`` (metrics.py) — counters/gauges/histograms over the hot
   paths (EventBus fan-in, SlicePool first-fit, scheduler decisions,
   checkpoint bytes+latency, heartbeat lag, restarts/kills/resizes),
@@ -26,10 +27,10 @@ from typing import Any, Dict, Optional
 from .analysis import ExperimentAnalysis, TrialRecord
 from .flightrec import FlightRecorder, SearchStateSnapshotter, json_safe
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
-from .tracing import NULL_TRACER, Span, Tracer
+from .tracing import NULL_TRACER, Span, Tracer, span
 
 __all__ = ["Observability", "NULL_OBS",
-           "Tracer", "Span", "NULL_TRACER",
+           "Tracer", "Span", "NULL_TRACER", "span",
            "MetricsRegistry", "Counter", "Gauge", "Histogram",
            "ExperimentAnalysis", "TrialRecord",
            "FlightRecorder", "SearchStateSnapshotter", "json_safe"]
@@ -42,6 +43,9 @@ class Observability:
 
     - ``trace``: falsy = tracing off; True = collect spans in memory; a path
       string = collect AND export Chrome trace-event JSON there on ``close()``.
+      While on, a JAX monitoring listener records each trace, lowering and
+      backend compile inside an open span as its ``jit.*`` child (wall clock
+      only); ``close()`` unregisters it.
     - ``metrics``: falsy = metrics off; True = registry only (queried in
       process); a path string = registry + JSONL snapshot stream at that path,
       flushed every ``metrics_interval`` clock-seconds (plus a final snapshot
@@ -72,6 +76,12 @@ class Observability:
         self._next_snap: Optional[float] = None
         self._mfile = None
         self._closed = False
+        self._jax_listener = None
+        if trace:
+            # jit.trace / jit.lower / jit.compile children of the open span.
+            import jax.monitoring  # lazy: importing repro.obs imports no jax
+            self._jax_listener = self.tracer.on_jax_event
+            jax.monitoring.register_event_time_span_listener(self._jax_listener)
         # Pre-resolved instruments for the event-routing hot path.
         if self.metrics is not None:
             self._m_hb_lag = self.metrics.histogram("hb.lag_s")
@@ -164,6 +174,11 @@ class Observability:
         self.tracer.end_all()
         self.snapshot(executor)
         self._closed = True
+        if self._jax_listener is not None:
+            import jax.monitoring
+            jax.monitoring.unregister_event_time_span_listener(
+                self._jax_listener)
+            self._jax_listener = None
         if self._mfile is not None:
             self._mfile.close()
             self._mfile = None

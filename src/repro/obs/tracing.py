@@ -2,7 +2,8 @@
 
 A span is one timed control-plane phase of one trial (DESIGN.md §8 taxonomy:
 ``trial``, ``schedule.decision``, ``slice.acquire``, ``build``, ``step``,
-``ckpt.save``, ``ckpt.restore``, ``resize``, ``restart``).  The ``trace`` of a
+``ckpt.save``, ``ckpt.restore``, ``resize``, ``restart``; inside a step,
+``data``, ``jit.trace``, ``jit.lower`` and ``jit.compile``).  The ``trace`` of a
 span is the trial id — every span of a trial's life, across retries, resizes
 and even process boundaries (worker children ship their spans back over the
 pipe protocol), lands on that trial's timeline row.
@@ -17,14 +18,26 @@ with fixed separators to keep that promise.  Real-time profiling numbers
 The disabled path is one attribute check: ``tracer.enabled`` is False on the
 shared null tracer, ``span()`` returns a reused no-op context manager, and
 ``record``/``begin``/``end`` return immediately.
+
+Nesting: while a ``tracer.span(...)`` body runs, the module-level ``span()``
+opens a child on the same tracer and trace, with the parent's name in its
+``parent`` arg, so code below the executor (a trainable's data and compile
+phases) is traced without a tracer argument.  With no span open, ``span()``
+costs one ``ContextVar.get()``.  On the wall clock each context span also
+enters ``jax.profiler.TraceAnnotation("tune." + name)``, so a profiler trace
+places the program's phases on the device timeline; a span recorded after the
+fact (``record``/``begin``/``end``) cannot be annotated.  ``on_jax_event``
+turns JAX's trace/lower/compile monitoring events into ``jit.*`` children of
+the open span.
 """
 from __future__ import annotations
 
+import contextvars
 import json
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ["Span", "Tracer", "NULL_TRACER"]
+__all__ = ["Span", "Tracer", "NULL_TRACER", "span"]
 
 # JSON-safe span-arg types; anything else is dropped at record time so a
 # span can never poison the export (or a SPAN bus event's JSONL record).
@@ -33,6 +46,25 @@ _JSON_SCALARS = (int, float, str, bool, type(None))
 # Wire format for spans crossing a thread/process boundary (SPAN bus events,
 # MSG_SPANS pipe messages): (name, ts, dur, cat, proc, args_dict).
 SpanTuple = Tuple[str, float, float, str, str, Dict[str, Any]]
+
+# The innermost open context span of this thread: (tracer, trace, name).
+_CURRENT: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_obs_span", default=None)
+
+# JAX monitoring time-span events -> the child span each becomes.  JAX stamps
+# them with ``time.time()``, the wall clock's own axis.
+_JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
+
+
+def _on_wall_clock(clock: Any) -> bool:
+    """Only the wall clock shares the profiler's time axis; a VirtualClock's
+    spans must stay deterministic."""
+    from ..core.clock import WallClock  # lazy: no import cycle
+    return isinstance(clock, WallClock)
 
 
 class Span:
@@ -70,14 +102,23 @@ class _NullSpanCtx:
     def arg(self, key: str, value: Any) -> None:
         pass
 
+    def set_trace(self, trace: str) -> None:
+        pass
+
+    def discard(self) -> None:
+        pass
+
 
 _NULL_CTX = _NullSpanCtx()
 
 
 class _SpanCtx:
-    """Live ``with tracer.span(...)`` body; ``arg()`` annotates before exit."""
+    """Live ``with tracer.span(...)`` body; ``arg()`` annotates before exit,
+    ``set_trace()`` names the trial once it is known, ``discard()`` drops the
+    span (its profiler annotation stays)."""
 
-    __slots__ = ("_tracer", "_name", "_trace", "_cat", "_proc", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_trace", "_cat", "_proc", "_args", "_t0",
+                 "_token", "_annot", "_keep")
 
     def __init__(self, tracer: "Tracer", name: str, trace: str, cat: str,
                  proc: str, args: Dict[str, Any]):
@@ -87,21 +128,52 @@ class _SpanCtx:
         self._cat = cat
         self._proc = proc
         self._args = args
+        self._keep = True
 
     def __enter__(self):
-        self._t0 = self._tracer.clock.time()
+        clock = self._tracer.clock
+        self._annot = None
+        if _on_wall_clock(clock):
+            from jax.profiler import TraceAnnotation  # lazy: obs imports no jax
+            self._annot = TraceAnnotation("tune." + self._name)
+            self._annot.__enter__()
+        self._token = _CURRENT.set((self._tracer, self._trace, self._name))
+        self._t0 = clock.time()
         return self
 
     def arg(self, key: str, value: Any) -> None:
         self._args[key] = value
 
+    def set_trace(self, trace: str) -> None:
+        self._trace = trace
+
+    def discard(self) -> None:
+        self._keep = False
+
     def __exit__(self, exc_type, exc, tb):
+        t1 = self._tracer.clock.time()
+        _CURRENT.reset(self._token)
+        if self._annot is not None:
+            self._annot.__exit__(None, None, None)
         if exc_type is not None:
             self._args.setdefault("error", exc_type.__name__)
-        self._tracer.record(self._name, self._trace, self._t0,
-                            self._tracer.clock.time() - self._t0,
-                            cat=self._cat, proc=self._proc, **self._args)
+        if self._keep:
+            self._tracer.record(self._name, self._trace, self._t0,
+                                t1 - self._t0, cat=self._cat, proc=self._proc,
+                                **self._args)
         return False
+
+
+def span(name: str, cat: str = "", **args: Any):
+    """A child of the innermost open context span: same tracer and trace (the
+    trial id), its parent's name in the ``parent`` arg.  With none open, the
+    shared no-op context."""
+    cur = _CURRENT.get()
+    if cur is None:
+        return _NULL_CTX
+    tracer, trace, parent = cur
+    args["parent"] = parent
+    return _SpanCtx(tracer, name, trace, cat, "host", args)
 
 
 class Tracer:
@@ -172,6 +244,21 @@ class Tracer:
         for name, ts, dur, cat, proc, args in spans:
             self.record(name, trace, float(ts), float(dur),
                         cat=str(cat), proc=str(proc), **dict(args))
+
+    def on_jax_event(self, event: str, start: float, end: float,
+                     **kwargs: Any) -> None:
+        """JAX monitoring time-span listener (``Observability`` registers it):
+        a trace, lowering or backend compile that runs inside one of this
+        tracer's open spans becomes its ``jit.*`` child, stamped with the
+        event's own start and end."""
+        name = _JIT_EVENTS.get(event)
+        if name is None:
+            return
+        cur = _CURRENT.get()
+        if cur is None or cur[0] is not self or not _on_wall_clock(self.clock):
+            return
+        self.record(name, cur[1], start, end - start, cat="compile",
+                    parent=cur[2], fun_name=kwargs.get("fun_name"))
 
     # -- introspection ---------------------------------------------------------------
     @property

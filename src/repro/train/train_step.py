@@ -38,7 +38,10 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, microbatch: int = 0):
     """
 
     def loss_fn(params, batch):
-        loss, metrics = forward_train(params, batch, cfg)
+        # Named scopes label the device ops (backward ones carry
+        # ``transpose(jvp(forward))``); they change metadata, not the program.
+        with jax.named_scope("forward"):
+            loss, metrics = forward_train(params, batch, cfg)
         return loss, metrics
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
@@ -74,7 +77,8 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, microbatch: int = 0):
             loss, metrics, grads = accumulated(state.params, batch)
         else:
             loss, metrics, grads = single(state.params, batch)
-        new_params, new_opt = opt.update(grads, state.opt_state, state.params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = opt.update(grads, state.opt_state, state.params)
         metrics = dict(metrics)
         metrics["grad_norm"] = global_norm(grads)
         metrics["total_loss"] = loss

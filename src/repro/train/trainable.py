@@ -18,6 +18,7 @@ from ..data.pipeline import DataConfig, SyntheticLMDataset
 from ..launch.mesh import HW
 from ..launch.roofline import analyze
 from ..models import ModelConfig, param_count
+from ..obs import span
 from .optimizer import adamw, linear_warmup_cosine, sgd
 from .train_step import TrainState, make_train_state, make_train_step
 
@@ -120,7 +121,8 @@ class ModelTrainable(Trainable):
         t0 = time.time()
         step_times = [] if self._pending_profile else None
         for _ in range(self.steps_per_iter):
-            batch = self._batch(self._global_step)
+            with span("data", cat="data", step=self._global_step):
+                batch = self._batch(self._global_step)
             if step_times is None:
                 self.state, metrics = self._step_fn(self.state, batch)
             else:

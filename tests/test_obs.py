@@ -16,7 +16,7 @@ from repro.core import (CheckpointManager, ConsoleLogger, EventType,
                         TrainableFactory, Trial, TrialEvent, TrialRunner,
                         TrialStatus, VirtualClock)
 from repro.obs import (NULL_OBS, NULL_TRACER, Counter, Gauge, Histogram,
-                       MetricsRegistry, Observability, Tracer)
+                       MetricsRegistry, Observability, Tracer, span)
 from repro.testing import crash_storm, run_scenario
 
 TESTS_DIR = os.path.dirname(os.path.abspath(__file__))
@@ -159,6 +159,110 @@ class TestTracer:
         # µs ints, rebased to the earliest span, dur floored at 1.
         assert by_name["sched"]["ts"] == 0 and by_name["sched"]["dur"] == 1000
         assert by_name["step"]["dur"] == 1_000_000
+
+
+class TestNestedSpans:
+    """``repro.obs.span``: a child of the open context span, or nothing."""
+
+    def test_no_op_with_no_span_open(self):
+        ctx = span("data", step=0)
+        assert ctx is span("other") is NULL_TRACER.span("x", "t")
+        with ctx as sp:
+            sp.arg("a", 1)
+            sp.set_trace("t")
+            sp.discard()
+
+    def test_no_op_under_a_disabled_tracer(self):
+        with NULL_TRACER.span("step", "t-1"):
+            assert span("data") is NULL_TRACER.span("x", "t")
+        tr = Tracer(clock=VirtualClock(), enabled=False)
+        with tr.span("step", "t-1"):
+            with span("data"):
+                pass
+        assert tr.spans == []
+
+    def test_child_carries_parent_name_and_trace(self):
+        vc = VirtualClock()
+        tr = Tracer(clock=vc)
+        with tr.span("step", "t-1", cat="train"):
+            vc.sleep(1.0)
+            with span("data", cat="data", step=4) as sp:
+                vc.sleep(0.5)
+                with span("inner"):
+                    vc.sleep(0.25)
+                sp.arg("rows", 8)
+        assert span("after") is NULL_TRACER.span("x", "t")  # context restored
+        by = {s.name: s for s in tr.spans}
+        assert set(by) == {"step", "data", "inner"}
+        data, inner, step = by["data"], by["inner"], by["step"]
+        assert data.trace == inner.trace == "t-1"
+        assert data.args == {"step": 4, "rows": 8, "parent": "step"}
+        assert data.cat == "data" and data.dur == 0.75
+        assert inner.args == {"parent": "data"} and inner.dur == 0.25
+        assert "parent" not in step.args and step.dur == 1.75
+        assert step.ts < data.ts < inner.ts
+
+    def test_each_tracer_keeps_its_children(self):
+        a, b = Tracer(clock=VirtualClock()), Tracer(clock=VirtualClock())
+        with a.span("step", "t-a"):
+            with b.span("build", "t-b"):
+                with span("data"):
+                    pass
+            with span("data"):
+                pass
+        assert [(s.name, s.args.get("parent")) for s in b.spans] == \
+            [("data", "build"), ("build", None)]
+        assert [(s.name, s.args.get("parent")) for s in a.spans] == \
+            [("data", "step"), ("step", None)]
+
+    def test_set_trace_and_discard(self):
+        tr = Tracer(clock=VirtualClock())
+        with tr.span("schedule.decision", cat="sched") as sp:
+            sp.set_trace("t-7")
+        with tr.span("schedule.decision", cat="sched") as sp:
+            sp.discard()
+        (s,) = tr.spans
+        assert s.trace == "t-7"
+
+    def test_jit_events_become_children_of_the_open_span(self):
+        from repro.core.clock import WallClock
+        tr = Tracer(clock=WallClock())
+        ev = "/jax/core/compile/jaxpr_trace_duration"
+        tr.on_jax_event(ev, 1.0, 2.0, fun_name="f")           # no span open
+        with tr.span("step", "t-1"):
+            tr.on_jax_event(ev, 1.0, 1.5, fun_name="train_step")
+            tr.on_jax_event("/jax/core/compile/backend_compile_duration",
+                            2.0, 4.0, fun_name="jit(train_step)")
+            tr.on_jax_event("/jax/other/event", 2.0, 3.0)
+            Tracer(clock=WallClock()).on_jax_event(ev, 1.0, 2.0)  # not its span
+        jit = [s for s in tr.spans if s.name.startswith("jit.")]
+        assert [(s.name, s.trace, s.ts, s.dur, s.cat, s.args) for s in jit] == [
+            ("jit.trace", "t-1", 1.0, 0.5, "compile",
+             {"parent": "step", "fun_name": "train_step"}),
+            ("jit.compile", "t-1", 2.0, 2.0, "compile",
+             {"parent": "step", "fun_name": "jit(train_step)"})]
+
+    def test_jit_events_are_ignored_on_a_virtual_clock(self):
+        tr = Tracer(clock=VirtualClock())
+        with tr.span("step", "t-1"):
+            tr.on_jax_event("/jax/core/compile/jaxpr_trace_duration", 1.0, 2.0)
+        assert [s.name for s in tr.spans] == ["step"]
+
+    def test_observability_listens_until_closed(self):
+        import jax
+        import jax.numpy as jnp
+        from repro.core.clock import WallClock
+        obs = Observability(trace=True, clock=WallClock())
+        tr = obs.tracer
+        with tr.span("step", "t-1"):
+            jax.jit(lambda x: x * 3.0 + 1.0)(jnp.ones(3))
+        names = {s.name for s in tr.spans}
+        assert {"jit.trace", "jit.lower", "jit.compile"} <= names
+        obs.close()
+        n = len(tr.spans)
+        with tr.span("step", "t-1"):
+            jax.jit(lambda x: x * 5.0 - 1.0)(jnp.ones(3))
+        assert [s.name for s in tr.spans[n:]] == ["step"]
 
 
 class TestNullObs:

@@ -229,3 +229,104 @@ class TestHardwareProfile:
         from repro.dist.submesh import SlicePool
         tr = self._trainable(_slice=SlicePool(n_virtual=4).acquire(2))
         assert all(not x.committed for x in jax.tree_util.tree_leaves(tr.state))
+
+
+class TestTrialTracing:
+    """Spans from inside a trial (DESIGN.md §8): ``data`` around each batch,
+    ``jit.*`` for the train step's trace, lowering and compile, all children
+    of the executor's ``step``, and their ``tune.`` profiler annotations."""
+
+    STEPS_PER_ITER = 2
+
+    @pytest.fixture(scope="class")
+    def run(self, tmp_path_factory):
+        from repro.core import (CheckpointManager, FIFOScheduler, ObjectStore,
+                                Resources, SerialMeshExecutor, Trial,
+                                TrialRunner)
+        from repro.obs import Observability
+        from repro.train.trainable import make_model_trainable
+
+        cls = make_model_trainable(TestHardwareProfile.CFG, batch=2, seq_len=16,
+                                   steps_per_iter=self.STEPS_PER_ITER,
+                                   total_steps=10)
+        obs = Observability(trace=True)
+        ex = SerialMeshExecutor(lambda _n: cls, CheckpointManager(ObjectStore()),
+                                total_devices=1, obs=obs)
+        stop = {"training_iteration": 2}
+        runner = TrialRunner(FIFOScheduler(metric="loss", mode="min"), ex,
+                             stopping_criteria=stop, obs=obs)
+        for lr in (1e-3, 2e-3):
+            runner.add_trial(Trial({"lr": lr}, resources=Resources(devices=1),
+                                   stopping_criteria=stop))
+        log_dir = str(tmp_path_factory.mktemp("profile"))
+        jax.profiler.start_trace(log_dir)
+        try:
+            trials = runner.run()
+        finally:
+            jax.profiler.stop_trace()
+        obs.close(ex)
+        assert all(t.status.value == "TERMINATED" for t in trials)
+        return [t.trial_id for t in trials], obs.tracer.spans, log_dir
+
+    def test_data_spans_per_step(self, run):
+        ids, spans, _ = run
+        steps = [s for s in spans if s.name == "step"]
+        assert len(steps) == 2 * len(ids)
+        for st_ in steps:
+            inside = [s for s in spans if s.name == "data" and s.trace == st_.trace
+                      and st_.ts <= s.ts <= st_.ts + st_.dur]
+            assert len(inside) == self.STEPS_PER_ITER
+            assert all(s.args["parent"] == "step" and s.cat == "data"
+                       for s in inside)
+        for tid in ids:
+            data = [s for s in spans if s.name == "data" and s.trace == tid]
+            assert [s.args["step"] for s in data] == list(range(4))
+        decisions = [s for s in spans if s.name == "schedule.decision"]
+        assert sorted(s.trace for s in decisions) == sorted(ids)
+
+    def test_one_trace_lower_compile_of_the_step_in_the_first_step(self, run):
+        ids, spans, _ = run
+        for tid in ids:
+            first = min((s for s in spans if s.name == "step" and s.trace == tid),
+                        key=lambda s: s.ts)
+            mine = [s for s in spans if s.name.startswith("jit.") and s.trace == tid
+                    and s.args.get("fun_name") in ("train_step", "jit(train_step)")]
+            assert sorted(s.name for s in mine) == ["jit.compile", "jit.lower",
+                                                    "jit.trace"]
+            for s in mine:
+                assert s.args["parent"] == "step" and s.cat == "compile"
+                assert first.ts <= s.ts and s.ts + s.dur <= first.ts + first.dur
+
+    def test_profiler_trace_holds_the_program_spans(self, run):
+        import glob
+        import os
+
+        from jax.profiler import ProfileData
+
+        path = max(glob.glob(os.path.join(run[2], "**", "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
+        names = set()
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                names |= {e.name for ln in plane.lines for e in ln.events}
+        assert {"tune.step", "tune.data", "tune.build",
+                "tune.schedule.decision"} <= names
+
+    def test_tracing_keeps_the_step_function(self):
+        from repro.core.clock import WallClock
+        from repro.obs import Observability
+        from repro.train.trainable import make_model_trainable
+
+        cls = make_model_trainable(TestHardwareProfile.CFG, batch=2, seq_len=16,
+                                   steps_per_iter=2, total_steps=10)
+        tr = cls({"lr": 1e-3})
+        fn = tr._step_fn
+        obs = Observability(trace=True, clock=WallClock())
+        try:
+            for it in range(2):
+                with obs.tracer.span("step", "t-1"):
+                    tr.step()
+        finally:
+            obs.close()
+        assert tr._step_fn is fn and fn._cache_size() == 1
+        assert len(obs.tracer.spans_named("data")) == 4
