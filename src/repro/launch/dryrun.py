@@ -31,7 +31,8 @@ from ..configs import get_config, list_archs
 from ..dist.sharding import (batch_specs, cache_specs, make_shardings,
                              param_specs, train_state_specs)
 from ..models import ModelConfig, decode_step, forward_encode, init_params, prefill
-from ..train import adamw, linear_warmup_cosine, make_train_state, make_train_step
+from ..train import (make_hyper_train_step, make_optimizer, make_train_state,
+                     optimizer_hypers)
 from .mesh import V5E, make_production_mesh
 from .roofline import analyze
 from .shapes import SHAPES, ShapeSpec, dryrun_config, input_specs, skip_reason
@@ -88,16 +89,19 @@ def lower_one(
     policy_ctx.__enter__()
 
     if shape.kind == "train":
-        opt = adamw(linear_warmup_cosine(3e-4, 100, 10_000),
-                    moment_dtype=cfg.opt_moment_dtype)
+        # The Tune trial's step: the optimizer's scalars are an argument.
+        hypers = optimizer_hypers("adamw", 10_000, {"lr": 3e-4, "warmup": 100})
+        opt = make_optimizer("adamw", hypers, moment_dtype=cfg.opt_moment_dtype)
         state_shapes = jax.eval_shape(
             partial(make_train_state, jax.random.key(0), cfg, opt))
         state_sh = make_shardings(train_state_specs(state_shapes, mesh, cfg), mesh)
         batch_sh = make_shardings(batch_specs(specs["batch"], mesh), mesh)
-        step = make_train_step(cfg, opt, microbatch=cfg.train_microbatch)
-        jitted = jax.jit(step, in_shardings=(state_sh, batch_sh),
+        step = make_hyper_train_step(cfg, "adamw", microbatch=cfg.train_microbatch,
+                                     moment_dtype=cfg.opt_moment_dtype)
+        jitted = jax.jit(step, in_shardings=(state_sh, batch_sh, None),
                          out_shardings=(state_sh, None), donate_argnums=(0,))
-        lowered = jitted.lower(state_shapes, specs["batch"])
+        hyper_shapes = {k: jax.ShapeDtypeStruct((), jnp.float32) for k in hypers}
+        lowered = jitted.lower(state_shapes, specs["batch"], hyper_shapes)
         n_tokens = shape.global_batch * shape.seq_len
     elif shape.kind == "prefill":
         params_shapes = jax.eval_shape(partial(init_params, jax.random.key(0), cfg))

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 from ..configs import get_config, list_archs
 from ..data.pipeline import DataConfig, SyntheticLMDataset, synthetic_batch
 from ..models import param_count
-from ..train import adamw, linear_warmup_cosine, make_train_state, make_train_step
+from ..train import (make_hyper_train_step, make_optimizer, make_train_state,
+                     optimizer_hypers)
 
 
 def main() -> None:
@@ -38,9 +39,12 @@ def main() -> None:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    opt = adamw(linear_warmup_cosine(args.lr, args.warmup, args.steps))
-    state = make_train_state(jax.random.key(0), cfg, opt)
-    step = jax.jit(make_train_step(cfg, opt))
+    hypers = optimizer_hypers("adamw", args.steps,
+                              {"lr": args.lr, "warmup": args.warmup})
+    state = make_train_state(jax.random.key(0), cfg,
+                             make_optimizer("adamw", hypers))
+    step = jax.jit(make_hyper_train_step(cfg, "adamw"))
+    hypers = {k: jnp.float32(v) for k, v in hypers.items()}
     print(f"[train] {cfg.arch_id} ({'reduced' if args.reduced else 'full'}): "
           f"{param_count(state.params):,} params")
 
@@ -56,7 +60,7 @@ def main() -> None:
     t0 = time.time()
     for i in range(args.steps):
         batch = {k: jnp.asarray(v) for k, v in batch_at(i).items()}
-        state, metrics = step(state, batch)
+        state, metrics = step(state, batch, hypers)
         if i % args.log_every == 0 or i == args.steps - 1:
             row = {"step": i, "loss": float(metrics["loss"]),
                    "accuracy": float(metrics["accuracy"]),

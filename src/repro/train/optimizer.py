@@ -4,12 +4,19 @@ Pytree-structured states; ``Optimizer`` is an (init, update) pair like optax —
 ``update`` returns (new_params, new_state) directly (fused apply) to avoid an
 extra tree round-trip.  AdamW keeps fp32 master moments regardless of param
 dtype (mixed-precision training convention).
+
+Every scalar (learning rate, schedule lengths, betas, momentum, weight decay,
+clip norm) may be a Python number or a float32 array traced by a jitted step;
+only the family, ``moment_dtype``, ``nesterov`` and clipping on or off
+(``grad_clip is None``) change the program.  ``make_optimizer`` builds an
+optimizer from one pytree of those scalars (``optimizer_hypers``), so a step
+that takes the pytree as an argument serves every trial of its shape.
 """
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +24,7 @@ import jax.numpy as jnp
 __all__ = [
     "Optimizer", "adamw", "sgd", "global_norm", "clip_by_global_norm",
     "cosine_schedule", "linear_warmup_cosine", "constant_schedule",
+    "make_optimizer", "optimizer_hypers",
 ]
 
 Schedule = Callable[[jax.Array], jax.Array]
@@ -28,7 +36,7 @@ def constant_schedule(lr: float) -> Schedule:
 
 def cosine_schedule(lr: float, total_steps: int, final_frac: float = 0.1) -> Schedule:
     def fn(step):
-        t = jnp.minimum(step.astype(jnp.float32) / max(total_steps, 1), 1.0)
+        t = jnp.minimum(step.astype(jnp.float32) / jnp.maximum(total_steps, 1), 1.0)
         cos = 0.5 * (1 + jnp.cos(math.pi * t))
         return lr * (final_frac + (1 - final_frac) * cos)
     return fn
@@ -36,10 +44,10 @@ def cosine_schedule(lr: float, total_steps: int, final_frac: float = 0.1) -> Sch
 
 def linear_warmup_cosine(lr: float, warmup: int, total_steps: int,
                          final_frac: float = 0.1) -> Schedule:
-    cos = cosine_schedule(lr, max(total_steps - warmup, 1), final_frac)
+    cos = cosine_schedule(lr, total_steps - warmup, final_frac)
     def fn(step):
         s = step.astype(jnp.float32)
-        warm = lr * s / max(warmup, 1)
+        warm = lr * s / jnp.maximum(warmup, 1)
         return jnp.where(s < warmup, warm, cos(jnp.maximum(s - warmup, 0)))
     return fn
 
@@ -150,3 +158,47 @@ def sgd(
                 {"step": step, "mom": treedef.unflatten([o[1] for o in out])})
 
     return Optimizer(init=init, update=update)
+
+
+# The scalar hyperparameters and their defaults, shared by the families and
+# then each family's own; a ``grad_clip`` of None turns clipping off.
+_SCHEDULE_HYPERS = {"lr": 3e-4, "warmup": 10}
+_FAMILY_HYPERS = {
+    "adamw": {"b1": 0.9, "b2": 0.95, "weight_decay": 0.1, "grad_clip": 1.0},
+    "sgd": {"momentum": 0.9, "weight_decay": 0.0, "grad_clip": None},
+}
+
+
+def optimizer_hypers(family: str, total_steps: int,
+                     config: Optional[Mapping[str, Any]] = None) -> Dict[str, float]:
+    """The scalar hyperparameters of ``family`` as one flat dict of floats:
+    ``config``'s values where it names them (other keys are ignored), else
+    the defaults.  ``warmup`` is a whole number of steps.  A ``grad_clip``
+    of None is left out, so the dict's keys say whether the step clips."""
+    if family not in _FAMILY_HYPERS:
+        raise ValueError(f"unknown optimizer {family!r}")
+    config = config or {}
+    scalars = {k: config.get(k, d)
+               for k, d in {**_SCHEDULE_HYPERS, **_FAMILY_HYPERS[family]}.items()}
+    scalars["warmup"] = int(scalars["warmup"])
+    scalars["total_steps"] = int(total_steps)
+    return {k: float(v) for k, v in scalars.items() if v is not None}
+
+
+def make_optimizer(family: str, hypers: Dict[str, Any],
+                   moment_dtype: Any = jnp.float32) -> Optimizer:
+    """The optimizer of ``family`` over the scalars of ``hypers`` (floats, or
+    float32 arrays traced by the step that calls this): a linear-warmup
+    cosine schedule, clipping iff ``hypers`` holds ``grad_clip``."""
+    schedule = linear_warmup_cosine(hypers["lr"], hypers["warmup"],
+                                    hypers["total_steps"])
+    if family == "adamw":
+        return adamw(schedule, b1=hypers["b1"], b2=hypers["b2"],
+                     weight_decay=hypers["weight_decay"],
+                     grad_clip=hypers.get("grad_clip"),
+                     moment_dtype=moment_dtype)
+    if family == "sgd":
+        return sgd(schedule, momentum=hypers["momentum"],
+                   weight_decay=hypers["weight_decay"],
+                   grad_clip=hypers.get("grad_clip"))
+    raise ValueError(f"unknown optimizer {family!r}")

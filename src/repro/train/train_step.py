@@ -1,20 +1,25 @@
 """Train / eval steps: loss + grad + optimizer apply, with optional
-gradient-accumulation microbatching.  Pure functions of (TrainState, batch) —
-this is what a Tune Trainable jit-compiles per trial, and what the dry-run
-lowers on the production mesh.
+gradient-accumulation microbatching.  Pure functions of (TrainState, batch),
+or of (TrainState, batch, hypers) with the optimizer's scalars as an
+argument: that form is what the dry-run lowers on the production mesh, and
+what every Tune trial of one shape shares, jit-compiled once per process
+(``shared_train_step``).
 """
 from __future__ import annotations
 
-from functools import partial
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+import threading
+from collections import OrderedDict
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..models import ModelConfig, forward_train, init_params
-from .optimizer import Optimizer, global_norm
+from .optimizer import Optimizer, global_norm, make_optimizer
 
-__all__ = ["TrainState", "make_train_state", "make_train_step", "make_eval_step"]
+__all__ = ["TrainState", "make_train_state", "make_train_step", "make_eval_step",
+           "make_hyper_train_step", "shared_train_step", "step_cache_info",
+           "step_cache_clear", "StepCacheInfo"]
 
 
 class TrainState(NamedTuple):
@@ -92,3 +97,98 @@ def make_eval_step(cfg: ModelConfig):
         loss, metrics = forward_train(params, batch, cfg)
         return metrics
     return eval_step
+
+
+def make_hyper_train_step(cfg: ModelConfig, family: str = "adamw",
+                          microbatch: int = 0, moment_dtype: Any = jnp.float32,
+                          factory: Optional[Callable] = None):
+    """Returns train_step(state, batch, hypers) -> (state, metrics).
+
+    The optimizer of ``family`` is built inside the trace from ``hypers``, a
+    flat dict of float32 scalars (``optimizer_hypers``), so one program
+    serves every value of them.  ``factory(cfg, opt, microbatch)`` makes the
+    (state, batch) step; ``make_train_step`` by default.
+    """
+    factory = factory or make_train_step
+
+    def train_step(state: TrainState, batch: Dict[str, jax.Array],
+                   hypers: Dict[str, jax.Array]) -> Tuple[TrainState, Dict]:
+        opt = make_optimizer(family, hypers, moment_dtype)
+        return factory(cfg, opt, microbatch)(state, batch)
+
+    return train_step
+
+
+class StepCacheInfo(NamedTuple):
+    hits: int
+    misses: int
+    maxsize: int
+    currsize: int
+
+
+class _StepCache:
+    """Least-recently-used map of key -> jitted step, safe across the
+    threads of a concurrent executor."""
+
+    def __init__(self, maxsize: int):
+        self.maxsize = maxsize
+        self._fns: "OrderedDict[tuple, Any]" = OrderedDict()
+        self._lock = threading.Lock()
+        self.hits = self.misses = 0
+
+    def get(self, key: tuple, build: Callable[[], Any]) -> Tuple[Any, bool]:
+        with self._lock:
+            fn = self._fns.get(key)
+            if fn is not None:
+                self._fns.move_to_end(key)
+                self.hits += 1
+                return fn, True
+            fn = self._fns[key] = build()
+            if len(self._fns) > self.maxsize:
+                self._fns.popitem(last=False)
+            self.misses += 1
+            return fn, False
+
+    def info(self) -> StepCacheInfo:
+        with self._lock:
+            return StepCacheInfo(self.hits, self.misses, self.maxsize,
+                                 len(self._fns))
+
+    def clear(self) -> None:
+        with self._lock:
+            self._fns.clear()
+            self.hits = self.misses = 0
+
+
+_STEP_CACHE = _StepCache(maxsize=8)
+
+
+def shared_train_step(cfg: ModelConfig, family: str, hypers: Dict[str, Any],
+                      microbatch: int = 0,
+                      factory: Optional[Callable] = None) -> Tuple[Any, bool]:
+    """(jit of ``make_hyper_train_step``, whether the lookup hit): one jitted
+    step for every caller with the same key, so trials that differ only in
+    their hyperparameters' values compile once per process.
+
+    The key is the model config, the family, the names in ``hypers`` (which
+    say whether the step clips), the microbatch, and the functions the trace
+    reads: the step factory and this module's ``forward_train``, so swapping
+    either builds a new program instead of reusing one traced from the old.
+    JAX's own cache keys the rest: shapes, dtypes and the committed device.
+    """
+    factory = factory or make_train_step
+    key = (cfg, family, tuple(sorted(hypers)), int(microbatch), factory,
+           forward_train)
+    return _STEP_CACHE.get(key, lambda: jax.jit(make_hyper_train_step(
+        cfg, family, microbatch, factory=factory)))
+
+
+def step_cache_info() -> StepCacheInfo:
+    """Hits, misses, maxsize and current size of ``shared_train_step``'s
+    cache, as ``functools`` reports a cache."""
+    return _STEP_CACHE.info()
+
+
+def step_cache_clear() -> None:
+    """Empty ``shared_train_step``'s cache and zero its counts."""
+    _STEP_CACHE.clear()

@@ -1,10 +1,13 @@
 """ModelTrainable — the bridge between the model zoo and the Tune core.
 
-One Tune *trial* = one ModelTrainable: a jit-compiled train step over a model
-config with trial hyperparameters (lr, warmup, weight decay, optimizer choice,
-microbatch, ...) pulled from ``config``.  Implements the full narrow-waist
-contract: step / save / restore / reset_config — so every scheduler
-(HyperBand pause/resume, PBT clone+mutate) works on real model training.
+One Tune *trial* = one ModelTrainable: a train step over a model config with
+trial hyperparameters (lr, warmup, weight decay, optimizer choice,
+microbatch, ...) pulled from ``config``.  The step is jit-compiled once per
+process for every trial of the same shape (``shared_train_step``); the
+trial's scalar hyperparameters are its argument, not constants in it.
+Implements the full narrow-waist contract: step / save / restore /
+reset_config — so every scheduler (HyperBand pause/resume, PBT clone+mutate)
+works on real model training.
 """
 from __future__ import annotations
 
@@ -12,6 +15,7 @@ import time
 from typing import Any, Dict, Optional
 
 import jax
+import numpy as np
 
 from ..core.api import Trainable
 from ..data.pipeline import DataConfig, SyntheticLMDataset
@@ -19,27 +23,11 @@ from ..launch.mesh import HW
 from ..launch.roofline import analyze
 from ..models import ModelConfig, param_count
 from ..obs import span
-from .optimizer import adamw, linear_warmup_cosine, sgd
-from .train_step import TrainState, make_train_state, make_train_step
+from .optimizer import make_optimizer, optimizer_hypers
+from .train_step import (TrainState, make_train_state, make_train_step,
+                         shared_train_step)
 
 __all__ = ["ModelTrainable", "make_model_trainable", "model_trainable_factory"]
-
-
-def _build_optimizer(hp: Dict[str, Any], total_steps: int):
-    name = hp.get("optimizer", "adamw")
-    lr = float(hp.get("lr", 3e-4))
-    schedule = linear_warmup_cosine(lr, int(hp.get("warmup", 10)), total_steps)
-    if name == "adamw":
-        return adamw(schedule,
-                     b1=float(hp.get("b1", 0.9)),
-                     b2=float(hp.get("b2", 0.95)),
-                     weight_decay=float(hp.get("weight_decay", 0.1)),
-                     grad_clip=hp.get("grad_clip", 1.0))
-    if name == "sgd":
-        return sgd(schedule, momentum=float(hp.get("momentum", 0.9)),
-                   weight_decay=float(hp.get("weight_decay", 0.0)),
-                   grad_clip=hp.get("grad_clip", None))
-    raise ValueError(f"unknown optimizer {name!r}")
 
 
 def _slice_device(sl: Any) -> Optional[jax.Device]:
@@ -61,7 +49,9 @@ class ModelTrainable(Trainable):
     Hardware profile (DESIGN.md §9): after every (re)build the first reported
     result carries a one-shot ``_profile`` entry in its metrics — step-time
     decomposition (first step = compile + execute vs steady state), device
-    memory, and with ``profile_roofline=True`` an achieved-vs-predicted
+    memory, ``step_cache`` ("hit" when the trial found its shape's step
+    already built in this process, "miss" when it built it), and with
+    ``profile_roofline=True`` an achieved-vs-predicted
     roofline tag from ``launch/roofline.py``.  The runner pops it off the
     metric stream and publishes it as trial metadata (``trial.profile``) plus
     a PROFILE event, so it rides the existing result transport across all
@@ -88,30 +78,47 @@ class ModelTrainable(Trainable):
         self._build(config)
 
     def _build(self, hp: Dict[str, Any]) -> None:
-        self._opt = _build_optimizer(hp, self.total_steps)
-        raw_step = make_train_step(self.model_cfg, self._opt,
-                                   microbatch=int(hp.get("microbatch", 0)))
+        self._configure(hp)
         seed = int(hp.get("init_seed", 0))
         with jax.default_device(self._device):
             state = make_train_state(jax.random.key(seed), self.model_cfg,
                                      self._opt)
         self.state = jax.device_put(state, self._device)
+        self._arm(hp)
+
+    def _configure(self, hp: Dict[str, Any]) -> None:
+        """The trial's optimizer and its shared step.  The scalars go to the
+        device once, as float32 arrays: a Python float would be weakly typed
+        (another trace) and cross from the host on every call."""
+        family = hp.get("optimizer", "adamw")
+        hypers = optimizer_hypers(family, self.total_steps, hp)
+        self._opt = make_optimizer(family, hypers)
+        self._shared_step, hit = shared_train_step(
+            self.model_cfg, family, hypers,
+            microbatch=int(hp.get("microbatch", 0)), factory=make_train_step)
+        self._step_cache = "hit" if hit else "miss"
+        self._hypers = jax.device_put(
+            {k: np.float32(v) for k, v in hypers.items()}, self._device)
+
+    def _arm(self, hp: Dict[str, Any]) -> None:
+        """Bind the step function to this trial's scalars and re-arm the
+        one-shot profile."""
         self._pending_profile = bool(hp.get("profile", True))
         self._compiled = None
         self._compile_s: Optional[float] = None
+        run = self._shared_step
         if hp.get("profile_roofline"):
             # AOT compile: one explicit lower+compile that doubles as the
-            # step function (the jit cache never compiles a second time) and
-            # hands the roofline walk the post-fusion HLO it needs — a
-            # traced-only jit exposes StableHLO, which the cost regexes
-            # cannot parse.
+            # step function and hands the roofline walk the post-fusion HLO
+            # it needs — a traced-only jit exposes StableHLO, which the cost
+            # regexes cannot parse.
             batch = self._batch(self._global_step)
             p0 = time.perf_counter()
-            self._compiled = jax.jit(raw_step).lower(self.state, batch).compile()
+            self._compiled = run.lower(self.state, batch, self._hypers).compile()
             self._compile_s = time.perf_counter() - p0
-            self._step_fn = self._compiled
-        else:
-            self._step_fn = jax.jit(raw_step)
+            run = self._compiled
+        hypers = self._hypers
+        self._step_fn = lambda state, batch: run(state, batch, hypers)
 
     def _batch(self, step: int) -> Dict[str, jax.Array]:
         return jax.device_put(self._data.batch_at(step), self._device)
@@ -164,6 +171,7 @@ class ModelTrainable(Trainable):
             "batch": self.batch,
             "seq_len": self.seq_len,
             "devices": [f"{d.platform}:{d.id}" for d in devices],
+            "step_cache": self._step_cache,
         }
         dev = devices[0]
         stats = dev.memory_stats() or {}  # None on the CPU backend
@@ -199,6 +207,8 @@ class ModelTrainable(Trainable):
         }
 
     def restore(self, snapshot: Any) -> None:
+        """The snapshot's state; the hyperparameters stay this trial's (a
+        PBT exploit restores a donor after ``reset_config``)."""
         state = TrainState(**jax.device_put(snapshot["state"], self._device))
         # A PBT mutation may have switched optimizer family: if the donor's
         # opt_state tree doesn't match this trainable's optimizer, re-init it
@@ -206,21 +216,26 @@ class ModelTrainable(Trainable):
         expect = jax.eval_shape(self._opt.init, state.params)
         if (jax.tree_util.tree_structure(expect)
                 != jax.tree_util.tree_structure(state.opt_state)):
-            state = TrainState(params=state.params,
-                               opt_state=self._opt.init(state.params),
-                               step=state.step)
+            state = state._replace(opt_state=self._fresh_opt_state(state.params))
         self.state = state
         self._global_step = int(snapshot["global_step"])
 
+    def _fresh_opt_state(self, params: Any) -> Any:
+        # Made as ``_build`` makes it, so its eager ops are the compiled ones,
+        # and committed to the trial's device like the rest of the state, so
+        # the step's call signature, and so its compiled program, is unchanged.
+        with jax.default_device(self._device):
+            return jax.device_put(self._opt.init(params), self._device)
+
     def reset_config(self, new_config: Dict[str, Any]) -> bool:
-        """PBT mutation: rebuild optimizer/step under new hypers, keep params."""
+        """PBT mutation: new hyperparameters and a fresh optimizer state,
+        same params.  The step is the shared one of the new config's key, so
+        a mutation of values alone compiles nothing."""
         self.config = dict(new_config)
-        params = self.state.params
-        step = self.state.step
-        self._build(new_config)
-        # keep model params; fresh optimizer state under the mutated hypers
-        self.state = TrainState(params=params,
-                                opt_state=self._opt.init(params), step=step)
+        self._configure(new_config)
+        self.state = self.state._replace(
+            opt_state=self._fresh_opt_state(self.state.params))
+        self._arm(new_config)
         return True
 
 

@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.launch.mesh import HW, V5E
-from repro.train import adamw, linear_warmup_cosine, make_train_state, make_train_step
+from repro.train import (make_hyper_train_step, make_optimizer, make_train_state,
+                         optimizer_hypers)
 
 fa, rg, rw, mr = (importlib.import_module(f"repro.kernels.{name}") for name in
                   ("flash_attention", "rglru_scan", "rwkv6_scan", "moe_router"))
@@ -88,13 +89,16 @@ def test_kernel_compiles(chip, name):
 
 def test_smollm_step_fits_one_chip(chip):
     cfg = dataclasses.replace(get_config("smollm-135m"), remat=True)
-    opt = adamw(linear_warmup_cosine(3e-4, 10, 1000))
+    # The step a Tune trial runs: the optimizer's scalars are an argument.
+    hypers = optimizer_hypers("adamw", 1000, {"lr": 3e-4, "warmup": 10})
+    opt = make_optimizer("adamw", hypers)
     state = jax.eval_shape(lambda: make_train_state(jax.random.key(0), cfg, opt))
     state = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), state)
     tok = jax.ShapeDtypeStruct((8, 2048), jnp.int32, sharding=chip)
-    compiled = jax.jit(make_train_step(cfg, opt)).lower(
-        state, {"tokens": tok, "labels": tok}).compile()
+    scalars = {k: jax.ShapeDtypeStruct((), jnp.float32, sharding=chip) for k in hypers}
+    compiled = jax.jit(make_hyper_train_step(cfg, "adamw")).lower(
+        state, {"tokens": tok, "labels": tok}, scalars).compile()
     ma = compiled.memory_analysis()
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)

@@ -1,4 +1,8 @@
-"""Optimizers, schedules, train step, data pipeline determinism."""
+"""Optimizers, schedules, train step, the shared step of a sweep's trials,
+data pipeline determinism."""
+import contextlib
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +14,9 @@ from repro.data import DataConfig, SyntheticLMDataset
 from repro.models import ModelConfig
 from repro.train import (TrainState, adamw, clip_by_global_norm,
                          cosine_schedule, global_norm, linear_warmup_cosine,
-                         make_train_state, make_train_step, sgd)
+                         make_hyper_train_step, make_train_state,
+                         make_train_step, optimizer_hypers, sgd,
+                         shared_train_step, step_cache_clear, step_cache_info)
 
 
 class TestOptimizers:
@@ -114,6 +120,192 @@ class TestTrainStep:
         step = jax.jit(make_train_step(cfg, opt))
         state, m = step(state, self._batch())
         assert int(state.step) == 1 and jnp.isfinite(m["total_loss"])
+
+
+# The train step's name in JAX's trace, lowering and compile events.
+STEP_NAMES = ("train_step", "jit(train_step)")
+_JIT_PHASES = {"/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+               "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+               "/jax/core/compile/backend_compile_duration": "jit.compile"}
+
+
+@contextlib.contextmanager
+def jit_events():
+    """(phase, fun_name) of every trace, lowering and compile in the body."""
+    events = []
+
+    def listen(event, start, end, **kw):
+        if event in _JIT_PHASES:
+            events.append((_JIT_PHASES[event], kw.get("fun_name")))
+
+    jax.monitoring.register_event_time_span_listener(listen)
+    try:
+        yield events
+    finally:
+        jax.monitoring.unregister_event_time_span_listener(listen)
+
+
+def step_phases(events):
+    return sorted(phase for phase, fun in events if fun in STEP_NAMES)
+
+
+class TestSharedStep:
+    """One jitted step per (model config, optimizer structure, microbatch):
+    a trial's scalar hyperparameters are its argument, so a sweep's trials of
+    one shape, and a PBT mutation, compile nothing new."""
+
+    # A shape no other test uses, and an empty cache, so what a test sees
+    # does not hang on what ran before it in the process.
+    CFG = ModelConfig(arch_id="shared", family="dense", n_layers=1, d_model=32,
+                      n_heads=2, n_kv_heads=1, d_ff=64, vocab_size=48).validate()
+
+    @pytest.fixture(autouse=True)
+    def _fresh_cache(self):
+        step_cache_clear()
+        yield
+        step_cache_clear()
+
+    def _cls(self, cfg=None):
+        from repro.train.trainable import make_model_trainable
+        return make_model_trainable(cfg or self.CFG, batch=4, seq_len=16,
+                                    steps_per_iter=2, total_steps=10)
+
+    def _batch(self, i):
+        data = SyntheticLMDataset(DataConfig(global_batch=4, seq_len=16,
+                                             vocab_size=self.CFG.vocab_size))
+        return {k: jnp.asarray(v) for k, v in data.batch_at(i).items()}
+
+    def test_trials_of_one_shape_share_one_compiled_step(self):
+        cls = self._cls()
+        with jit_events() as first:
+            a = cls({"lr": 1e-3, "weight_decay": 0.1})
+            pa = a.step()["_profile"]
+        with jit_events() as second:
+            b = cls({"lr": 2e-3, "weight_decay": 0.05})
+            pb = b.step()["_profile"]
+        assert step_phases(first) == ["jit.compile", "jit.lower", "jit.trace"]
+        assert step_phases(second) == []
+        info = step_cache_info()
+        assert (info.hits, info.misses, info.currsize) == (1, 1, 1)
+        assert (pa["step_cache"], pb["step_cache"]) == ("miss", "hit")
+        assert a._shared_step is b._shared_step
+
+    @pytest.mark.parametrize("family", ["adamw", "sgd"])
+    def test_traced_hypers_match_the_step_with_constants_baked_in(self, family):
+        """Three steps with the scalars as arguments against the same steps
+        with them written into the program as constants."""
+        if family == "adamw":
+            config = {"lr": 3e-3, "warmup": 2, "weight_decay": 0.05, "b1": 0.8}
+            baked_opt = adamw(linear_warmup_cosine(3e-3, 2, 10), b1=0.8,
+                              b2=0.95, weight_decay=0.05, grad_clip=1.0)
+        else:
+            config = {"lr": 0.05, "warmup": 2, "momentum": 0.8,
+                      "weight_decay": 0.01, "grad_clip": 0.5}
+            baked_opt = sgd(linear_warmup_cosine(0.05, 2, 10), momentum=0.8,
+                            weight_decay=0.01, grad_clip=0.5)
+        hypers = {k: jnp.float32(v)
+                  for k, v in optimizer_hypers(family, 10, config).items()}
+        baked = jax.jit(make_train_step(self.CFG, baked_opt))
+        traced = jax.jit(make_hyper_train_step(self.CFG, family))
+        s_baked = s_traced = make_train_state(jax.random.key(0), self.CFG, baked_opt)
+        for i in range(3):
+            s_baked, m_baked = baked(s_baked, self._batch(i))
+            s_traced, m_traced = traced(s_traced, self._batch(i), hypers)
+            np.testing.assert_allclose(float(m_traced["loss"]),
+                                       float(m_baked["loss"]), rtol=1e-6)
+        for got, want in zip(jax.tree_util.tree_leaves(s_traced),
+                             jax.tree_util.tree_leaves(s_baked)):
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+        assert traced._cache_size() == 1
+
+    @pytest.mark.parametrize("change", ["microbatch", "optimizer", "grad_clip",
+                                        "model"])
+    def test_another_structure_misses(self, change):
+        base = {"lr": 1e-3}
+        assert self._cls()(base)._step_cache == "miss"
+        if change == "model":
+            other = self._cls(dataclasses.replace(self.CFG, d_ff=96))(base)
+        else:
+            value = {"microbatch": 2, "optimizer": "sgd", "grad_clip": None}[change]
+            other = self._cls()({**base, change: value})
+        assert other._step_cache == "miss"
+        info = step_cache_info()
+        assert (info.hits, info.misses, info.currsize) == (0, 2, 2)
+        assert other.step()["loss"] > 0
+
+    def test_reset_config_compiles_nothing_and_steps_with_the_new_lr(self):
+        from repro.dist.submesh import SlicePool
+        # On a real slice, so the state is committed to its device.
+        tr = self._cls()({"lr": 1e-3,
+                          "_slice": SlicePool(devices=jax.devices()[:1]).acquire(1)})
+        before = tr.state.params
+        tr.step()
+        assert not _same(tr.state.params, before)
+        with jit_events() as events:
+            assert tr.reset_config({"lr": 0.0})
+            before = tr.state.params
+            out = tr.step()
+        assert [phase for phase, _ in events if phase == "jit.compile"] == []
+        assert step_phases(events) == []
+        assert out["_profile"]["step_cache"] == "hit"
+        assert float(tr._hypers["lr"]) == 0.0
+        assert _same(tr.state.params, before)  # a zero learning rate moves nothing
+
+    def test_restore_after_reset_config_keeps_the_mutated_hypers(self):
+        """PBT's exploit: ``reset_config`` to the mutated config, then
+        ``restore`` of the donor's snapshot."""
+        cls = self._cls()
+        donor = cls({"lr": 1e-3, "weight_decay": 0.1})
+        donor.step()
+        snapshot = donor.save()
+        tr = cls({"lr": 2e-3, "weight_decay": 0.1})
+        tr.step()
+        assert tr.reset_config({"lr": 0.0, "weight_decay": 0.2})
+        tr.restore(snapshot)
+        assert _same(tr.state.params, donor.state.params)
+        assert float(tr._hypers["lr"]) == 0.0
+        assert float(tr._hypers["weight_decay"]) == pytest.approx(0.2)
+        tr.step()
+        assert _same(tr.state.params, donor.state.params)
+        assert step_cache_info().misses == 1
+
+    def test_concurrent_lookups_build_each_step_once(self):
+        """Trials of the concurrent executor look the step up from their own
+        threads: each key is built once and every count is kept."""
+        import sys
+        import threading
+
+        n_threads, n_lookups, microbatches = 32, 60, (0, 2, 4)
+        hypers = optimizer_hypers("adamw", 10)
+        got = {mb: [] for mb in microbatches}
+        start = threading.Barrier(n_threads)
+
+        def look():
+            start.wait(timeout=30)
+            for i in range(n_lookups):
+                mb = microbatches[i % len(microbatches)]
+                got[mb].append(shared_train_step(self.CFG, "adamw", hypers,
+                                                 microbatch=mb)[0])
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [threading.Thread(target=look) for _ in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads)
+        info = step_cache_info()
+        assert (info.misses, info.hits) == (3, n_threads * n_lookups - 3)
+        assert all(len({id(fn) for fn in fns}) == 1 for fns in got.values())
+
+
+def _same(a, b):
+    return all(np.array_equal(x, y) for x, y in
+               zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)))
 
 
 class TestDataPipeline:
@@ -246,6 +438,8 @@ class TestTrialTracing:
         from repro.obs import Observability
         from repro.train.trainable import make_model_trainable
 
+        step_cache_clear()  # the first trial builds the step, whatever ran before
+
         cls = make_model_trainable(TestHardwareProfile.CFG, batch=2, seq_len=16,
                                    steps_per_iter=self.STEPS_PER_ITER,
                                    total_steps=10)
@@ -285,17 +479,20 @@ class TestTrialTracing:
         assert sorted(s.trace for s in decisions) == sorted(ids)
 
     def test_one_trace_lower_compile_of_the_step_in_the_first_step(self, run):
+        """The first trial traces, lowers and compiles the shared step in its
+        first step; the second, of the same shape, does none of them."""
         ids, spans, _ = run
-        for tid in ids:
-            first = min((s for s in spans if s.name == "step" and s.trace == tid),
-                        key=lambda s: s.ts)
-            mine = [s for s in spans if s.name.startswith("jit.") and s.trace == tid
-                    and s.args.get("fun_name") in ("train_step", "jit(train_step)")]
-            assert sorted(s.name for s in mine) == ["jit.compile", "jit.lower",
-                                                    "jit.trace"]
-            for s in mine:
-                assert s.args["parent"] == "step" and s.cat == "compile"
-                assert first.ts <= s.ts and s.ts + s.dur <= first.ts + first.dur
+        first_trial, second_trial = ids
+        first = min((s for s in spans if s.name == "step" and s.trace == first_trial),
+                    key=lambda s: s.ts)
+        mine = lambda tid: [s for s in spans if s.name.startswith("jit.")
+                            and s.trace == tid and s.args.get("fun_name") in STEP_NAMES]
+        assert sorted(s.name for s in mine(first_trial)) == ["jit.compile", "jit.lower",
+                                                             "jit.trace"]
+        for s in mine(first_trial):
+            assert s.args["parent"] == "step" and s.cat == "compile"
+            assert first.ts <= s.ts and s.ts + s.dur <= first.ts + first.dur
+        assert mine(second_trial) == []
 
     def test_profiler_trace_holds_the_program_spans(self, run):
         import glob
@@ -317,6 +514,7 @@ class TestTrialTracing:
         from repro.obs import Observability
         from repro.train.trainable import make_model_trainable
 
+        step_cache_clear()
         cls = make_model_trainable(TestHardwareProfile.CFG, batch=2, seq_len=16,
                                    steps_per_iter=2, total_steps=10)
         tr = cls({"lr": 1e-3})
@@ -328,5 +526,5 @@ class TestTrialTracing:
                     tr.step()
         finally:
             obs.close()
-        assert tr._step_fn is fn and fn._cache_size() == 1
+        assert tr._step_fn is fn and tr._shared_step._cache_size() == 1
         assert len(obs.tracer.spans_named("data")) == 4
