@@ -60,6 +60,8 @@ class ModelConfig:
     frontend_dim: int = 512               # conv-feature / projected-patch width
     n_prefix_embeds: int = 256            # VLM: image patches per sequence
     # -- numerics ------------------------------------------------------------------
+    norm_eps: float = 1e-6                # epsilon of every RMSNorm / LayerNorm
+    matmul_precision: Optional[str] = None  # model matmuls: None = JAX's default | highest
     param_dtype: str = "float32"
     activation_dtype: str = "float32"
     remat: bool = False                   # per-layer activation checkpointing
@@ -106,6 +108,8 @@ class ModelConfig:
     def validate(self) -> "ModelConfig":
         if self.family not in ("dense", "moe", "ssm", "hybrid", "audio", "vlm"):
             raise ValueError(f"unknown family {self.family!r}")
+        if self.matmul_precision not in (None, "highest"):
+            raise ValueError(f"unknown matmul_precision {self.matmul_precision!r}")
         if self.family == "moe" and self.moe is None:
             raise ValueError("moe family requires moe config")
         if self.n_heads and self.kv_heads and self.n_heads % self.kv_heads != 0:

@@ -37,7 +37,7 @@ def init_norm(d: int, cfg: ModelConfig) -> Params:
     return p
 
 
-def apply_norm(p: Params, x: jax.Array, cfg: ModelConfig, eps: float = 1e-6) -> jax.Array:
+def apply_norm(p: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     # Reductions in fp32 (numerics), multiplies in the activation dtype: a full
     # fp32 copy of x would otherwise be saved as a backward residual — at
     # (L, B, S, D) stacked over a scanned layer stack that doubles activation
@@ -45,11 +45,11 @@ def apply_norm(p: Params, x: jax.Array, cfg: ModelConfig, eps: float = 1e-6) -> 
     if cfg.norm == "layernorm":
         mu = x.astype(jnp.float32).mean(-1, keepdims=True)
         var = jnp.square(x.astype(jnp.float32) - mu).mean(-1, keepdims=True)
-        inv = jax.lax.rsqrt(var + eps)
+        inv = jax.lax.rsqrt(var + cfg.norm_eps)
         y = (x - mu.astype(x.dtype)) * inv.astype(x.dtype)
         return y * p["scale"].astype(x.dtype) + p["bias"].astype(x.dtype)
     var = jnp.square(x.astype(jnp.float32)).mean(-1, keepdims=True)
-    inv = jax.lax.rsqrt(var + eps)
+    inv = jax.lax.rsqrt(var + cfg.norm_eps)
     return x * inv.astype(x.dtype) * p["scale"].astype(x.dtype)
 
 

@@ -8,6 +8,7 @@ text token embeddings (PaliGemma's prefix-LM layout).
 """
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -30,6 +31,8 @@ def init_params(key, cfg: ModelConfig) -> Params:
         "stack": T.init_stack(ks[1], cfg),
         "final_norm": L.init_norm(cfg.d_model, cfg),
     }
+    if cfg.family == "ssm":
+        p["ln0"] = L.init_norm(cfg.d_model, cfg)
     head = L.init_lm_head(ks[2], cfg)
     if head is not None:
         p["lm_head"] = head
@@ -48,6 +51,13 @@ def param_count(params: Params) -> int:
 
 # -- input embedding ---------------------------------------------------------------
 
+def _embed_tokens(params: Params, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
+    x = L.embed_tokens(params["embed"], tokens, cfg)
+    if cfg.family == "ssm":  # RWKV normalises the embeddings before block 0 (ln0)
+        x = L.apply_norm(params["ln0"], x, cfg)
+    return x
+
+
 def _embed_inputs(params: Params, batch: Dict[str, jax.Array], cfg: ModelConfig) -> jax.Array:
     from ..dist.sharding import constrain
     adt = jnp.dtype(cfg.activation_dtype)
@@ -58,7 +68,7 @@ def _embed_inputs(params: Params, batch: Dict[str, jax.Array], cfg: ModelConfig)
         txt = L.embed_tokens(params["embed"], batch["tokens"], cfg)
         x = jnp.concatenate([img, txt], axis=1)
     else:
-        x = L.embed_tokens(params["embed"], batch["tokens"], cfg)
+        x = _embed_tokens(params, batch["tokens"], cfg)
     return constrain(x)
 
 
@@ -86,49 +96,63 @@ def cross_entropy(logits: jax.Array, labels: jax.Array,
 
 # -- forward passes -----------------------------------------------------------------
 
+def _precision(cfg: ModelConfig):
+    """The model's matmuls at ``cfg.matmul_precision`` where it is set (their
+    gradients inherit it); JAX's default (one bfloat16 pass per float32
+    matmul on a TPU) otherwise.  Training in float32 on a TPU needs
+    "highest" where the first gradient must be checked against a float32
+    reference: at the default it reads as far off as bfloat16 compute."""
+    if cfg.matmul_precision is None:
+        return contextlib.nullcontext()
+    return jax.default_matmul_precision(cfg.matmul_precision)
+
+
 def forward_train(params: Params, batch: Dict[str, jax.Array],
                   cfg: ModelConfig) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Returns (total_loss, metrics).  batch needs family-appropriate inputs
     plus "labels" (and optional "loss_mask")."""
-    x = _embed_inputs(params, batch, cfg)
-    B, S, _ = x.shape
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    x, _, aux = T.apply_stack(params["stack"], cfg, x, positions, None, mode="train")
-    logits = _logits(params, x, cfg)
+    with _precision(cfg):
+        x = _embed_inputs(params, batch, cfg)
+        B, S, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+        x, _, aux = T.apply_stack(params["stack"], cfg, x, positions, None, mode="train")
+        logits = _logits(params, x, cfg)
 
-    labels = batch["labels"]
-    mask = batch.get("loss_mask")
-    if cfg.frontend == "vision_stub":
-        # loss only over the text region (after the image prefix)
-        P = batch["patch_embeds"].shape[1]
-        logits = logits[:, P:]
-    ce, acc = cross_entropy(logits, labels, mask)
-    aux_coef = cfg.moe.aux_loss_coef if cfg.moe else 0.0
-    loss = ce + aux_coef * aux
-    return loss, {"loss": ce, "aux_loss": aux, "accuracy": acc}
+        labels = batch["labels"]
+        mask = batch.get("loss_mask")
+        if cfg.frontend == "vision_stub":
+            # loss only over the text region (after the image prefix)
+            P = batch["patch_embeds"].shape[1]
+            logits = logits[:, P:]
+        ce, acc = cross_entropy(logits, labels, mask)
+        aux_coef = cfg.moe.aux_loss_coef if cfg.moe else 0.0
+        loss = ce + aux_coef * aux
+        return loss, {"loss": ce, "aux_loss": aux, "accuracy": acc}
 
 
 def forward_encode(params: Params, batch: Dict[str, jax.Array],
                    cfg: ModelConfig) -> jax.Array:
     """Encoder-only / no-cache forward returning full logits (hubert prefill)."""
-    x = _embed_inputs(params, batch, cfg)
-    B, S, _ = x.shape
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    x, _, _ = T.apply_stack(params["stack"], cfg, x, positions, None, mode="train")
-    return _logits(params, x, cfg)
+    with _precision(cfg):
+        x = _embed_inputs(params, batch, cfg)
+        B, S, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+        x, _, _ = T.apply_stack(params["stack"], cfg, x, positions, None, mode="train")
+        return _logits(params, x, cfg)
 
 
 def prefill(params: Params, batch: Dict[str, jax.Array], cfg: ModelConfig,
             max_len: int) -> Tuple[jax.Array, List[Any]]:
     """Process a prompt, fill caches sized ``max_len``; return (last-token
     logits (B, V), caches)."""
-    x = _embed_inputs(params, batch, cfg)
-    B, S, _ = x.shape
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    caches = T.init_caches(cfg, B, max_len)
-    x, caches, _ = T.apply_stack(params["stack"], cfg, x, positions, caches,
-                                 mode="prefill")
-    logits = _logits(params, x[:, -1:], cfg)
+    with _precision(cfg):
+        x = _embed_inputs(params, batch, cfg)
+        B, S, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+        caches = T.init_caches(cfg, B, max_len)
+        x, caches, _ = T.apply_stack(params["stack"], cfg, x, positions, caches,
+                                     mode="prefill")
+        logits = _logits(params, x[:, -1:], cfg)
     return logits[:, 0], caches
 
 
@@ -136,10 +160,11 @@ def decode_step(params: Params, caches: List[Any], tokens: jax.Array,
                 pos: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, List[Any]]:
     """One synchronized decode step.  tokens (B,) int32, pos scalar int32.
     Returns (logits (B, V), updated caches)."""
-    x = L.embed_tokens(params["embed"], tokens[:, None], cfg)
-    B = x.shape[0]
-    positions = jnp.broadcast_to(pos.astype(jnp.int32)[None, None], (B, 1))
-    x, caches, _ = T.apply_stack(params["stack"], cfg, x, positions, caches,
-                                 mode="decode")
-    logits = _logits(params, x, cfg)
+    with _precision(cfg):
+        x = _embed_tokens(params, tokens[:, None], cfg)
+        B = x.shape[0]
+        positions = jnp.broadcast_to(pos.astype(jnp.int32)[None, None], (B, 1))
+        x, caches, _ = T.apply_stack(params["stack"], cfg, x, positions, caches,
+                                     mode="decode")
+        logits = _logits(params, x, cfg)
     return logits[:, 0], caches

@@ -12,6 +12,9 @@ space (ratios are always <= 1, so no overflow); inter-chunk state is carried by
 (kernels/rwkv6_scan.py) implements the same chunked scheme with VMEM tiling.
 
 Channel-mixing: squared-ReLU MLP with static token-shift (Finch eq. 20-22).
+
+Device ops carry the named scopes ``rwkv6.time_mix`` (with ``rwkv6.wkv``
+inside it) and ``rwkv6.channel_mix``.
 """
 from __future__ import annotations
 
@@ -29,6 +32,10 @@ Params = Dict[str, Any]
 _N_MIX = 5  # r, k, v, w, g
 _LORA_MIX = 32
 _LORA_DECAY = 64
+# w0 + lora above this would make -exp(.) overflow float32 to -inf, and the
+# chunked form's log-space cumsum NaN (-inf - -inf); exp(-exp(8)) is already
+# 0 in float32, so the clip changes no decay.
+_LOG_DECAY_MAX = 8.0
 
 
 def init_time_mix(key, cfg: ModelConfig) -> Params:
@@ -90,8 +97,8 @@ def _ddlerp(p: Params, x: jax.Array, s: jax.Array) -> jax.Array:
 def _decay(p: Params, xw: jax.Array) -> jax.Array:
     """log-decay (negative), fp32: logw = -exp(w0 + lora_w(xw))."""
     lora = jnp.tanh(xw @ p["w_lora_a"].astype(xw.dtype)) @ p["w_lora_b"].astype(xw.dtype)
-    return -jnp.exp(jnp.clip((p["w0"].astype(jnp.float32) + lora.astype(jnp.float32)),
-                             -10.0, 8.0))
+    return -jnp.exp(jnp.minimum(p["w0"].astype(jnp.float32) + lora.astype(jnp.float32),
+                                _LOG_DECAY_MAX))
 
 
 def _group_norm(p: Params, y: jax.Array, n_heads: int, eps: float = 64e-5) -> jax.Array:
@@ -107,7 +114,11 @@ def _group_norm(p: Params, y: jax.Array, n_heads: int, eps: float = 64e-5) -> ja
 
 def _wkv_chunked(r, k, v, logw, u, state, chunk: int):
     """Exact chunked WKV.  r/k/v: (B,S,H,N); logw fp32 (B,S,H,N); u (H,N);
-    state (B,H,N,N) fp32.  Returns (y (B,S,H,N), new_state)."""
+    state (B,H,N,N) fp32.  Returns (y (B,S,H,N), new_state).
+
+    Each chunk is recomputed in the backward pass: otherwise the backward
+    keeps every chunk's (B,L,L,H,N) decay ratios, S*L*H*N floats a tensor
+    (1 GiB each at 1 x 4096, L = 32, 32 heads of 64), and keeps several."""
     B, S, H, N = r.shape
     L = min(chunk, S)
     n_chunks = -(-S // L)
@@ -141,7 +152,7 @@ def _wkv_chunked(r, k, v, logw, u, state, chunk: int):
         S_new = decay_all[..., None] * S0 + jnp.einsum("bthn,bthm->bhnm", k_scaled, vb32)
         return S_new, (y_intra + y_inter).astype(r.dtype)
 
-    state, ys = jax.lax.scan(body, state, (rc, kc, vc, wc))
+    state, ys = jax.lax.scan(jax.checkpoint(body), state, (rc, kc, vc, wc))
     y = ys.swapaxes(0, 1).reshape(B, n_chunks * L, H, N)
     return y[:, :S], state
 
@@ -165,27 +176,29 @@ def apply_time_mix(
     B, S, D = x.shape
     N = cfg.rwkv_head_dim
     H = D // N
-    prev = state["prev"] if state else None
-    s = _token_shift(x, prev)
-    xr, xk, xv, xw, xg = _ddlerp(p, x, s)
-    r = (xr @ p["w_r"].astype(x.dtype)).reshape(B, S, H, N)
-    k = (xk @ p["w_k"].astype(x.dtype)).reshape(B, S, H, N)
-    v = (xv @ p["w_v"].astype(x.dtype)).reshape(B, S, H, N)
-    g = jax.nn.silu(xg @ p["w_g"].astype(x.dtype))
-    logw = _decay(p, xw).reshape(B, S, H, N)
+    with jax.named_scope("rwkv6.time_mix"):
+        prev = state["prev"] if state else None
+        s = _token_shift(x, prev)
+        xr, xk, xv, xw, xg = _ddlerp(p, x, s)
+        r = (xr @ p["w_r"].astype(x.dtype)).reshape(B, S, H, N)
+        k = (xk @ p["w_k"].astype(x.dtype)).reshape(B, S, H, N)
+        v = (xv @ p["w_v"].astype(x.dtype)).reshape(B, S, H, N)
+        g = jax.nn.silu(xg @ p["w_g"].astype(x.dtype))
+        logw = _decay(p, xw).reshape(B, S, H, N)
 
-    wkv0 = state["wkv"] if state else jnp.zeros((B, H, N, N), jnp.float32)
-    if S == 1:
-        y, wkv = _wkv_step(r[:, 0], k[:, 0], v[:, 0], logw[:, 0], p["u"], wkv0)
-        y = y[:, None]
-    elif cfg.kernel_impl == "pallas":
-        from ..kernels import ops as kops
-        y, wkv = kops.rwkv6_scan(r, k, v, logw, p["u"], wkv0, chunk=chunk)
-    else:
-        y, wkv = _wkv_chunked(r, k, v, logw, p["u"], wkv0, chunk)
+        wkv0 = state["wkv"] if state else jnp.zeros((B, H, N, N), jnp.float32)
+        with jax.named_scope("rwkv6.wkv"):
+            if S == 1:
+                y, wkv = _wkv_step(r[:, 0], k[:, 0], v[:, 0], logw[:, 0], p["u"], wkv0)
+                y = y[:, None]
+            elif cfg.kernel_impl == "pallas":
+                from ..kernels import ops as kops
+                y, wkv = kops.rwkv6_scan(r, k, v, logw, p["u"], wkv0, chunk=chunk)
+            else:
+                y, wkv = _wkv_chunked(r, k, v, logw, p["u"], wkv0, chunk)
 
-    y = _group_norm(p, y.reshape(B, S, D), H) * g
-    out = y @ p["w_o"].astype(x.dtype)
+        y = _group_norm(p, y.reshape(B, S, D), H) * g
+        out = y @ p["w_o"].astype(x.dtype)
     return out, {"prev": x[:, -1], "wkv": wkv}
 
 
@@ -193,14 +206,16 @@ def apply_channel_mix(
     p: Params, x: jax.Array, cfg: ModelConfig,
     state: Optional[Dict[str, jax.Array]] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    prev = state["prev"] if state else None
-    s = _token_shift(x, prev)
-    xk = x + (s - x) * p["mu_k"].astype(x.dtype)
-    xr = x + (s - x) * p["mu_r"].astype(x.dtype)
-    k = jnp.square(jax.nn.relu(xk @ p["w_k"].astype(x.dtype)))
-    v = k @ p["w_v"].astype(x.dtype)
-    rgate = jax.nn.sigmoid(xr @ p["w_r"].astype(x.dtype))
-    return rgate * v, {"prev": x[:, -1]}
+    with jax.named_scope("rwkv6.channel_mix"):
+        prev = state["prev"] if state else None
+        s = _token_shift(x, prev)
+        xk = x + (s - x) * p["mu_k"].astype(x.dtype)
+        xr = x + (s - x) * p["mu_r"].astype(x.dtype)
+        k = jnp.square(jax.nn.relu(xk @ p["w_k"].astype(x.dtype)))
+        v = k @ p["w_v"].astype(x.dtype)
+        rgate = jax.nn.sigmoid(xr @ p["w_r"].astype(x.dtype))
+        out = rgate * v
+    return out, {"prev": x[:, -1]}
 
 
 def init_state(cfg: ModelConfig, batch: int) -> Dict[str, jax.Array]:

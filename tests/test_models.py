@@ -1,6 +1,7 @@
 """Model zoo correctness: decode==forward consistency, chunked-scan
 equivalence, family-specific behaviours."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +83,122 @@ class TestRWKVChunking:
         y_c, st_c = _wkv_chunked(r, k, v, logw, u, s0, chunk)
         np.testing.assert_allclose(y_c, y_ref, atol=1e-4)
         np.testing.assert_allclose(st_c, st, atol=1e-4)
+
+    def test_chunked_gradient_equals_sequential(self):
+        """Each chunk is recomputed in the backward pass; the gradient is
+        the single-step recurrence's."""
+        key = jax.random.key(4)
+        B, S, H, N = 1, 21, 2, 8
+        r, k, v = (jax.random.normal(jax.random.fold_in(key, i), (B, S, H, N)) * 0.5
+                   for i in range(3))
+        logw = -jnp.exp(jax.random.normal(jax.random.fold_in(key, 4), (B, S, H, N)) * 0.5 - 2)
+        u = jax.random.normal(jax.random.fold_in(key, 5), (H, N)) * 0.3
+        s0 = jnp.zeros((B, H, N, N))
+        w = jax.random.normal(jax.random.fold_in(key, 6), (B, S, H, N))
+
+        def sequential(r, k, v, logw, u):
+            ys, st = [], s0
+            for t in range(S):
+                y, st = _wkv_step(r[:, t], k[:, t], v[:, t], logw[:, t], u, st)
+                ys.append(y)
+            return jnp.sum(jnp.stack(ys, 1) * w)
+
+        def chunked(r, k, v, logw, u):
+            return jnp.sum(_wkv_chunked(r, k, v, logw, u, s0, 8)[0] * w)
+
+        args = (r, k, v, logw, u)
+        want = jax.grad(sequential, argnums=range(5))(*args)
+        got = jax.grad(chunked, argnums=range(5))(*args)
+        for g, e in zip(got, want):
+            np.testing.assert_allclose(g, e, atol=1e-4)
+
+    def test_decay_cap_keeps_huge_log_decay_finite(self):
+        """w0 far above the cap: the decay is 0, not NaN."""
+        cfg = lm_cfg(family="ssm", n_heads=2, rwkv_head_dim=32, norm="layernorm")
+        params = init_params(jax.random.key(0), cfg)
+        params["stack"][0]["blocks"][0]["tm"]["w0"] = jnp.full_like(
+            params["stack"][0]["blocks"][0]["tm"]["w0"], 200.0)
+        toks = jax.random.randint(jax.random.key(1), (2, 40), 0, V)
+        loss, grads = jax.value_and_grad(
+            lambda p: forward_train(p, {"tokens": toks, "labels": toks}, cfg)[0])(params)
+        assert np.isfinite(float(loss))
+        assert all(bool(jnp.isfinite(g).all()) for g in jax.tree_util.tree_leaves(grads))
+
+    def test_scopes_name_the_time_mix_wkv_and_channel_mix(self):
+        """The compiled train step's ops carry the named scopes, forward and
+        backward, for a device trace to attribute."""
+        cfg = lm_cfg(family="ssm", n_layers=1, n_heads=2, rwkv_head_dim=32,
+                     norm="layernorm")
+        params = init_params(jax.random.key(0), cfg)
+        toks = jnp.zeros((1, 16), jnp.int32)
+        grad = jax.jit(jax.grad(
+            lambda p: forward_train(p, {"tokens": toks, "labels": toks}, cfg)[0]))
+        ops = set(re.findall(r'op_name="([^"]*)"', grad.lower(params).compile().as_text()))
+        for scope in ("rwkv6.time_mix/", "rwkv6.time_mix/rwkv6.wkv/", "rwkv6.channel_mix/"):
+            assert any(scope in op and "transpose(" not in op for op in ops), scope
+            assert any(scope in op and "transpose(" in op for op in ops), scope
+
+
+class TestNorms:
+    @pytest.mark.parametrize("norm", ["layernorm", "rmsnorm"])
+    def test_norm_eps_is_the_configs(self, norm):
+        from repro.models.layers import apply_norm, init_norm
+
+        x = jax.random.normal(jax.random.key(0), (3, 64)) * 1e-2
+        out = {}
+        for eps in (1e-6, 1e-3):
+            cfg = lm_cfg(norm=norm, norm_eps=eps)
+            out[eps] = apply_norm(init_norm(64, cfg), x, cfg)
+            mean = x.mean(-1, keepdims=True) if norm == "layernorm" else 0.0
+            var = jnp.square(x - mean).mean(-1, keepdims=True)
+            np.testing.assert_allclose(out[eps], (x - mean) / jnp.sqrt(var + eps),
+                                       rtol=1e-4, atol=1e-5)
+        assert not np.allclose(out[1e-6], out[1e-3])
+
+    def test_default_eps_is_1e6(self):
+        assert ModelConfig.norm_eps == 1e-6
+
+    def test_ln0_only_in_rwkv(self):
+        """RWKV normalises the embeddings (``ln0``) before block 0; no
+        other family has the parameter."""
+        ssm = lm_cfg(family="ssm", n_heads=2, rwkv_head_dim=32, norm="layernorm")
+        assert "ln0" in init_params(jax.random.key(0), ssm)
+        assert "ln0" not in init_params(jax.random.key(0), lm_cfg())
+        params = init_params(jax.random.key(0), ssm)
+        toks = jax.random.randint(jax.random.key(1), (2, 12), 0, V)
+        base = forward_encode(params, {"tokens": toks}, ssm)
+        params["ln0"]["bias"] = params["ln0"]["bias"] + 0.5
+        assert not np.allclose(forward_encode(params, {"tokens": toks}, ssm), base)
+
+
+class TestMatmulPrecision:
+    @staticmethod
+    def _dot_precisions(cfg):
+        """The precision attribute of every dot in the lowered train
+        gradient (None where a dot has none, JAX's default)."""
+        params = init_params(jax.random.key(0), cfg)
+        toks = jnp.zeros((1, 16), jnp.int32)
+        grad = jax.jit(jax.grad(
+            lambda p: forward_train(p, {"tokens": toks, "labels": toks}, cfg)[0]))
+        dots = [ln for ln in grad.lower(params).as_text().splitlines()
+                if "stablehlo.dot_general" in ln]
+        assert dots
+        return {(re.search(r"precision = \[(\w+)", ln) or [None, None])[1] for ln in dots}
+
+    @pytest.mark.parametrize("family", ["dense", "ssm"])
+    def test_unset_leaves_jax_default(self, family):
+        cfg = lm_cfg(family=family, n_heads=2, rwkv_head_dim=32, norm="layernorm")
+        assert self._dot_precisions(cfg) <= {None, "DEFAULT"}
+
+    def test_highest_reaches_every_dot_forward_and_backward(self):
+        cfg = lm_cfg(family="ssm", n_heads=2, rwkv_head_dim=32, norm="layernorm",
+                     matmul_precision="highest")
+        assert self._dot_precisions(cfg) == {"HIGHEST"}
+
+    @pytest.mark.parametrize("precision", ["high", "fp64"])
+    def test_other_precisions_are_refused(self, precision):
+        with pytest.raises(ValueError, match="matmul_precision"):
+            lm_cfg(matmul_precision=precision)
 
 
 class TestRGLRU:

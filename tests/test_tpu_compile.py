@@ -103,3 +103,24 @@ def test_smollm_step_fits_one_chip(chip):
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert used <= HW[V5E].hbm_bytes, f"{used / 2**30:.2f} GiB > 16 GiB"
+
+
+def test_rwkv6_step_fits_one_chip(chip):
+    """The benchmark's RWKV-6 trial: every published width, 4 of the 24
+    layers, one sequence of 4096, remat, float32 with its matmuls at
+    HIGHEST."""
+    cfg = dataclasses.replace(get_config("rwkv6-1.6b"), n_layers=4, remat=True,
+                              matmul_precision="highest")
+    hypers = optimizer_hypers("adamw", 1000, {"lr": 1e-3, "warmup": 2})
+    opt = make_optimizer("adamw", hypers)
+    state = jax.eval_shape(lambda: make_train_state(jax.random.key(0), cfg, opt))
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), state)
+    tok = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=chip)
+    scalars = {k: jax.ShapeDtypeStruct((), jnp.float32, sharding=chip) for k in hypers}
+    compiled = jax.jit(make_hyper_train_step(cfg, "adamw")).lower(
+        state, {"tokens": tok, "labels": tok}, scalars).compile()
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert used <= HW[V5E].hbm_bytes, f"{used / 2**30:.2f} GiB > 16 GiB"
