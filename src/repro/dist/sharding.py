@@ -44,7 +44,7 @@ from jax.sharding import PartitionSpec as P
 __all__ = [
     "spec_for", "param_specs", "train_state_specs", "batch_specs",
     "cache_specs", "make_shardings", "constrain", "sharding_strategy",
-    "activation_policy", "STRATEGIES",
+    "activation_policy", "activations_partitioned", "STRATEGIES",
 ]
 
 STRATEGIES = ("fsdp_tp", "dp_only")
@@ -81,6 +81,14 @@ def activation_policy(mesh, seq_parallel: bool = False):
         yield
     finally:
         _state["act_mesh"], _state["seq_parallel"] = prev
+
+
+def activations_partitioned() -> bool:
+    """True while an ``activation_policy`` spreads activations over more than
+    one device: the step is then partitioned by XLA, which cannot partition a
+    Pallas kernel, so model code takes its jnp path."""
+    mesh = _state["act_mesh"]
+    return mesh is not None and mesh.size > 1
 
 
 # -- mesh helpers -------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Handles block-size padding (each kernel requires divisible shapes) and the
-choice of execution: on TPU the call sites lower to real Mosaic kernels; on
+Handles block-size padding of the scan and router kernels (each requires
+divisible shapes; flash attention pads itself) and the choice of execution: on TPU the call sites lower to real Mosaic kernels; on
 the CPU they run with ``interpret=True`` (the kernel body runs as it would on
 TPU, minus the tiling), which is how the tests check them.  Any other backend
 is refused rather than interpreted.
@@ -46,24 +46,15 @@ def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     q_pos: jax.Array, k_pos: jax.Array,
     causal: bool = True, window: Optional[int] = None,
-    softcap: Optional[float] = None,
-    block_q: int = _fa.DEFAULT_BLOCK_Q, block_k: int = _fa.DEFAULT_BLOCK_K,
+    softcap: Optional[float] = None, precision: Optional[str] = None,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
 ) -> jax.Array:
-    """Padded/dispatching wrapper; see flash_attention_pallas for the contract.
-
-    Padding: extra q rows compute garbage that is sliced off; extra k slots get
-    k_pos = -1 which the mask rejects."""
-    Sq, Sk = q.shape[1], k.shape[1]
-    bq, bk = min(block_q, Sq), min(block_k, Sk)
-    q, pq = _pad_to(q, 1, bq)
-    q_pos, _ = _pad_to(q_pos, 1, bq, value=0)
-    k, pk = _pad_to(k, 1, bk)
-    v, _ = _pad_to(v, 1, bk)
-    k_pos, _ = _pad_to(k_pos, 1, bk, value=-1)
-    out = _fa.flash_attention_pallas(
+    """flash_attention_pallas, interpreted on the CPU; see it for the
+    contract (it pads to its blocks)."""
+    return _fa.flash_attention_pallas(
         q, k, v, q_pos, k_pos, causal=causal, window=window, softcap=softcap,
-        block_q=bq, block_k=bk, interpret=use_interpret())
-    return out[:, :Sq] if pq else out
+        precision=precision, block_q=block_q, block_k=block_k,
+        interpret=use_interpret())
 
 
 def rwkv6_scan(

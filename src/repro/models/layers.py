@@ -1,6 +1,6 @@
-"""Core layers: norms, RoPE, GQA/MQA attention (naive + chunked online-softmax),
-gated MLPs, embeddings.  Pure JAX; the Pallas flash kernel plugs in via
-``attn_impl='pallas'`` (kernels/ops.py).
+"""Core layers: norms, RoPE, GQA/MQA attention (naive + chunked online-softmax,
+or the Pallas flash kernel of kernels/ops.py: ``attn_impl``, see
+``attn_impl()``), gated MLPs, embeddings.
 
 Parameter containers are plain nested dicts so they stack cleanly for
 scan-over-layers and shard via path-based rules (dist/sharding.py).
@@ -183,25 +183,50 @@ def project_qkv(p: Params, x: jax.Array, cfg: ModelConfig,
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
+def attn_impl(impl: str, sq: int, sk: int) -> str:
+    """The attention path a call takes: ``naive``, ``chunked`` or ``flash``.
+
+    ``auto`` picks from the platform and the shapes: decode (one query)
+    stays naive; on a TPU, a step XLA does not partition takes the Pallas
+    flash kernel, whose S x S scores live only in VMEM, forward and
+    backward; elsewhere the jnp paths (naive up to 2048 keys, chunked
+    above).  An explicit choice is honoured; ``pallas`` and ``chunked`` fall
+    back to naive for one query."""
+    if impl == "auto":
+        from ..dist.sharding import activations_partitioned
+        if sq > 1 and jax.default_backend() == "tpu" and not activations_partitioned():
+            return "flash"
+        impl = "chunked" if sk > 2048 else "naive"
+    if impl not in ("naive", "chunked", "pallas"):
+        raise ValueError(f"unknown attn_impl {impl!r}")
+    if sq == 1 or impl == "naive":
+        return "naive"
+    return "flash" if impl == "pallas" else "chunked"
+
+
 def attend(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
            q_pos: jax.Array, k_pos: jax.Array, cfg: ModelConfig,
            causal: bool = True, window: Optional[int] = None,
            impl: Optional[str] = None) -> jax.Array:
-    """Scaled-dot-product attention core with mask from positions."""
-    impl = impl or cfg.attn_impl
-    S = q.shape[1]
-    if impl == "auto":
-        impl = "chunked" if (k_all.shape[1] > 2048 and S > 1) else "naive"
-    if impl == "pallas" and S > 1:
-        from ..kernels import ops as kops
-        return kops.flash_attention(q, k_all, v_all, q_pos, k_pos,
-                                    causal=causal, window=window,
-                                    softcap=cfg.logit_softcap)
-    if impl == "chunked" and S > 1:
-        return _sdpa_chunked(q, k_all, v_all, q_pos, k_pos, causal, window,
-                             cfg.logit_softcap, cfg.attn_chunk)
-    bias = _mask_bias(q_pos, k_pos, causal, window)
-    return _sdpa(q, k_all, v_all, bias, cfg.logit_softcap)
+    """Scaled-dot-product attention core with mask from positions.  The
+    path taken is counted, at trace time, as ``attention.impl.<path>`` in
+    ``repro.obs.PROCESS_METRICS``; its ops carry the scope
+    ``attention.<path>``."""
+    from ..obs import PROCESS_METRICS
+    path = attn_impl(impl or cfg.attn_impl, q.shape[1], k_all.shape[1])
+    PROCESS_METRICS.counter(f"attention.impl.{path}").inc()
+    with jax.named_scope(f"attention.{path}"):
+        if path == "flash":
+            from ..kernels import ops as kops
+            return kops.flash_attention(q, k_all, v_all, q_pos, k_pos,
+                                        causal=causal, window=window,
+                                        softcap=cfg.logit_softcap,
+                                        precision=cfg.matmul_precision)
+        if path == "chunked":
+            return _sdpa_chunked(q, k_all, v_all, q_pos, k_pos, causal, window,
+                                 cfg.logit_softcap, cfg.attn_chunk)
+        bias = _mask_bias(q_pos, k_pos, causal, window)
+        return _sdpa(q, k_all, v_all, bias, cfg.logit_softcap)
 
 
 def attention(

@@ -34,12 +34,14 @@ class TestPallasModelPaths:
         b = forward_encode(params, {"tokens": toks}, cfg_pl)
         np.testing.assert_allclose(a, b, atol=atol)
 
+    # At "highest" both paths compute in float32; at the default the kernel
+    # rounds its MXU operands to bfloat16, as a TPU does (tests/test_models.py).
     def test_flash_attention_in_model(self):
-        cfg = lm_cfg(attn_impl="naive")
+        cfg = lm_cfg(attn_impl="naive", matmul_precision="highest")
         self._compare(cfg, dataclasses.replace(cfg, attn_impl="pallas"))
 
     def test_flash_attention_swa_in_model(self):
-        cfg = lm_cfg(attn_impl="naive", sliding_window=16)
+        cfg = lm_cfg(attn_impl="naive", sliding_window=16, matmul_precision="highest")
         self._compare(cfg, dataclasses.replace(cfg, attn_impl="pallas"))
 
     def test_rwkv6_pallas_scan_in_model(self):

@@ -4,6 +4,9 @@ Kernels execute in interpret mode on CPU (the kernel body is what's tested;
 tiling is TPU-side).  Hypothesis drives shape fuzzing on top of the explicit
 parametrized sweeps.
 """
+import functools
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +15,8 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
+
+fa = importlib.import_module("repro.kernels.flash_attention")
 
 KEY = jax.random.key(0)
 
@@ -45,12 +50,15 @@ class TestFlashAttention:
         v = randn(3, (B, Sk, K, hd), dtype)
         qp = jnp.broadcast_to(jnp.arange(Sk - Sq, Sk)[None], (B, Sq))
         kp = jnp.broadcast_to(jnp.arange(Sk)[None], (B, Sk))
-        out = ops.flash_attention(q, k, v, qp, kp, causal=True,
-                                  block_q=64, block_k=64)
         exp = ref.flash_attention_ref(q, k, v, qp, kp, causal=True)
-        atol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-        np.testing.assert_allclose(np.asarray(out, np.float32),
-                                   np.asarray(exp, np.float32), atol=atol)
+        # "highest" keeps float32 operands; at the default the MXU operands
+        # are bfloat16 (what JAX's default does to a float32 matmul on a TPU).
+        for precision in ("highest", None):
+            out = ops.flash_attention(q, k, v, qp, kp, causal=True,
+                                      precision=precision, block_q=64, block_k=64)
+            atol = 2e-5 if dtype == jnp.float32 and precision else 2e-2
+            np.testing.assert_allclose(np.asarray(out, np.float32),
+                                       np.asarray(exp, np.float32), atol=atol)
 
     @pytest.mark.parametrize("causal,window,softcap", [
         (True, None, None), (False, None, None),
@@ -62,7 +70,7 @@ class TestFlashAttention:
         pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         out = ops.flash_attention(q, k, v, pos, pos, causal=causal,
                                   window=window, softcap=softcap,
-                                  block_q=64, block_k=64)
+                                  precision="highest", block_q=64, block_k=64)
         exp = ref.flash_attention_ref(q, k, v, pos, pos, causal=causal,
                                       window=window, softcap=softcap)
         np.testing.assert_allclose(out, exp, atol=2e-5)
@@ -77,7 +85,7 @@ class TestFlashAttention:
         kp_full = jnp.broadcast_to(jnp.arange(36, 36 + Sk)[None], (B, Sk))
         kp_holes = kp_full.at[:, 64:].set(-1)
         out = ops.flash_attention(q, k, v, qp, kp_holes, causal=True,
-                                  block_q=64, block_k=64)
+                                  precision="highest", block_q=64, block_k=64)
         exp = ref.flash_attention_ref(q, k[:, :64], v[:, :64], qp,
                                       kp_full[:, :64], causal=True)
         np.testing.assert_allclose(out, exp, atol=2e-5)
@@ -89,7 +97,8 @@ class TestFlashAttention:
         v = randn(3, (B, Sk, K, hd))
         qp = jnp.full((B, 1), Sk - 1)
         kp = jnp.broadcast_to(jnp.arange(Sk)[None], (B, Sk))
-        out = ops.flash_attention(q, k, v, qp, kp, causal=True)
+        out = ops.flash_attention(q, k, v, qp, kp, causal=True,
+                                  precision="highest")
         exp = ref.flash_attention_ref(q, k, v, qp, kp, causal=True)
         np.testing.assert_allclose(out, exp, atol=2e-5)
 
@@ -102,9 +111,93 @@ class TestFlashAttention:
         v = randn(3, (B, Sk, K, hd))
         qp = jnp.broadcast_to(jnp.arange(Sk - Sq, Sk)[None], (B, Sq))
         kp = jnp.broadcast_to(jnp.arange(Sk)[None], (B, Sk))
-        out = ops.flash_attention(q, k, v, qp, kp, block_q=32, block_k=32)
+        out = ops.flash_attention(q, k, v, qp, kp, precision="highest",
+                                  block_q=32, block_k=32)
         exp = ref.flash_attention_ref(q, k, v, qp, kp)
         np.testing.assert_allclose(out, exp, atol=3e-5)
+
+    # (causal, window, softcap, G, Sq, Sk, holes): masks, GQA groups, ragged
+    # lengths that pad to the 64-blocks, and ring-cache holes.
+    GRAD_CASES = [
+        (True, None, None, 1, 128, 128, False),
+        (True, None, None, 3, 128, 128, False),
+        (False, None, None, 3, 128, 128, False),
+        (True, 32, None, 3, 128, 128, False),
+        (True, None, 20.0, 1, 128, 128, False),
+        (True, None, None, 3, 96, 160, False),
+        (True, None, None, 1, 64, 128, True),
+    ]
+
+    @staticmethod
+    def _grad_inputs(G, Sq, Sk, holes, B=2, K=1, hd=32):
+        q = randn(1, (B, Sq, K * G, hd))
+        k = randn(2, (B, Sk, K, hd))
+        v = randn(3, (B, Sk, K, hd))
+        qp = jnp.broadcast_to(jnp.arange(Sk - Sq, Sk)[None], (B, Sq))
+        kp = jnp.broadcast_to(jnp.arange(Sk)[None], (B, Sk))
+        if holes:  # unfilled ring slots, a whole key block among them
+            kp = kp.at[:, 40:110].set(-1)
+        cot = randn(4, q.shape)  # a cotangent that weighs every output
+        return (q, k, v), qp, kp, cot
+
+    @pytest.mark.parametrize("causal,window,softcap,G,Sq,Sk,holes", GRAD_CASES)
+    @pytest.mark.parametrize("precision", ["highest", None])
+    def test_gradients(self, causal, window, softcap, G, Sq, Sk, holes, precision):
+        """jax.grad through the kernel's custom VJP against the grad of the
+        plain reference at HIGHEST: tight at "highest", within bfloat16
+        operand rounding at the default."""
+        qkv, qp, kp, cot = self._grad_inputs(G, Sq, Sk, holes)
+        kw = dict(causal=causal, window=window, softcap=softcap)
+
+        def loss(fn):
+            return lambda q, k, v: jnp.sum(fn(q, k, v, qp, kp, **kw) * cot)
+
+        got = jax.grad(loss(functools.partial(
+            ops.flash_attention, precision=precision, block_q=64, block_k=64)),
+            (0, 1, 2))(*qkv)
+        with jax.default_matmul_precision("highest"):
+            want = jax.grad(loss(ref.flash_attention_ref), (0, 1, 2))(*qkv)
+        rtol = 2e-6 if precision else 2e-2
+        for name, g, w in zip("qkv", got, want):
+            err = float(jnp.abs(g - w).max() / jnp.abs(w).max())
+            assert err < rtol, f"d{name}: {err:.2e}"
+
+    def test_causal_block_skipping_matches_unskipped(self, monkeypatch):
+        """Causal blocks above the diagonal do no work; with every block run
+        (masked element by element) the output and gradients are the same."""
+        qkv, qp, kp, cot = self._grad_inputs(3, 256, 256, False)
+
+        def run():
+            f = lambda q, k, v: ops.flash_attention(q, k, v, qp, kp, block_q=64,
+                                                    block_k=64)
+            out, vjp = jax.vjp(f, *qkv)
+            return (out,) + vjp(cot)
+
+        by_q, by_k = fa._block_spans(
+            qp, kp, fa._Static(True, None, None, None, 64, 64, True))
+        # (first live, last live) per sequence and block: live up to the
+        # diagonal block, and nothing above it.
+        want = ([0, 0, 0, 0], [0, 1, 2, 3], [0, 1, 2, 3], [3, 3, 3, 3])
+        for got, w in zip(by_q + by_k, want):
+            np.testing.assert_array_equal(got, w * 2)
+        skipped = run()
+        everything = lambda q_pos, k_pos, st: ((jnp.zeros(8, jnp.int32),
+                                                jnp.full(8, 3, jnp.int32)),) * 2
+        monkeypatch.setattr(fa, "_block_spans", everything)
+        for a, b in zip(skipped, run()):
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("hd,precision,want", [
+        (64, None, 1024), (80, None, 1024), (128, None, 1024), (256, None, 512),
+        (512, None, 256), (64, "highest", 512), (256, "highest", 256),
+        (1024, "highest", 128)])
+    def test_block_sizes_shrink_with_the_head(self, hd, precision, want):
+        """A block's VMEM stays what it is at head_dim 128: the block halves
+        for each doubling of the head beyond it, and again at "highest"."""
+        assert fa.block_sizes(4096, 4096, hd, precision) == (want, want)
+        # A shorter length is one block.
+        assert fa.block_sizes(want // 2 + 8, 2048, hd, precision) == (
+            want // 2 + 8, want)
 
 
 class TestRWKV6Scan:

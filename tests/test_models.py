@@ -13,6 +13,8 @@ from repro.models import (ModelConfig, MoEConfig, decode_step, forward_encode,
 from repro.models.rwkv6 import _wkv_chunked, _wkv_step
 from repro.models.rglru import _rglru_scan
 from repro.models.moe import apply_moe_layer, init_moe_layer
+from repro.models.layers import attn_impl
+from repro.obs import PROCESS_METRICS
 
 V = 96
 
@@ -199,6 +201,99 @@ class TestMatmulPrecision:
     def test_other_precisions_are_refused(self, precision):
         with pytest.raises(ValueError, match="matmul_precision"):
             lm_cfg(matmul_precision=precision)
+
+
+class TestAttentionDispatch:
+    @pytest.mark.parametrize("impl,sq,sk,backend,path", [
+        ("auto", 2048, 2048, "tpu", "flash"),    # the train step on a chip
+        ("auto", 7, 4096, "tpu", "flash"),       # prefill
+        ("auto", 1, 2048, "tpu", "naive"),       # decode
+        ("auto", 2048, 2048, "cpu", "naive"),
+        ("auto", 4096, 4096, "cpu", "chunked"),
+        ("auto", 1, 4096, "cpu", "naive"),
+        ("naive", 2048, 2048, "tpu", "naive"),
+        ("chunked", 2048, 2048, "tpu", "chunked"),
+        ("pallas", 64, 64, "cpu", "flash"),
+        ("pallas", 1, 64, "tpu", "naive"),
+    ])
+    def test_path_from_backend_and_shape(self, monkeypatch, impl, sq, sk,
+                                         backend, path):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert attn_impl(impl, sq, sk) == path
+
+    def test_partitioned_step_keeps_the_jnp_path(self, monkeypatch):
+        """XLA cannot partition a Pallas kernel: under an activation policy
+        over several devices, ``auto`` stays on the jnp paths."""
+        from types import SimpleNamespace
+
+        from repro.dist.sharding import activation_policy
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with activation_policy(SimpleNamespace(size=4)):
+            assert attn_impl("auto", 2048, 2048) == "naive"
+        with activation_policy(SimpleNamespace(size=1)):
+            assert attn_impl("auto", 2048, 2048) == "flash"
+
+    def test_unknown_impl_is_refused(self):
+        with pytest.raises(ValueError, match="attn_impl"):
+            attn_impl("flash2", 16, 16)
+
+    def test_counter_counts_the_path_taken(self, monkeypatch):
+        """``attention.impl.<path>`` counts each attention call traced."""
+        cfg = lm_cfg()
+        params = init_params(jax.random.key(0), cfg)
+        batch = {"tokens": jnp.zeros((2, 16), jnp.int32),
+                 "labels": jnp.zeros((2, 16), jnp.int32)}
+
+        def traced():
+            jax.eval_shape(lambda p: forward_train(p, batch, cfg)[0], params)
+            return {p: PROCESS_METRICS.counter(f"attention.impl.{p}").value
+                    for p in ("flash", "naive", "chunked")}
+
+        before = {p: PROCESS_METRICS.counter(f"attention.impl.{p}").value
+                  for p in ("flash", "naive", "chunked")}
+        on_cpu = traced()
+        calls = on_cpu["naive"] - before["naive"]
+        assert calls >= 1 and on_cpu["flash"] == before["flash"]
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        on_tpu = traced()  # traced only: nothing lowers for the chip here
+        assert on_tpu["flash"] - on_cpu["flash"] == calls
+        assert on_tpu["naive"] == on_cpu["naive"]
+        assert on_tpu["chunked"] == before["chunked"]
+
+    def test_scopes_name_the_path(self):
+        cfg = lm_cfg(n_layers=1, attn_impl="pallas")
+        params = init_params(jax.random.key(0), cfg)
+        toks = jnp.zeros((1, 16), jnp.int32)
+        grad = jax.jit(jax.grad(
+            lambda p: forward_train(p, {"tokens": toks, "labels": toks}, cfg)[0]))
+        text = grad.lower(params).as_text(debug_info=True)
+        assert "attention.flash" in text and "attention.naive" not in text
+
+    def test_reduced_smollm_kernel_matches_naive(self):
+        """A reduced SmolLM stack (GQA, remat): loss and gradients through the
+        flash kernel (interpret mode, bfloat16 MXU operands at the default
+        precision) against the naive path in float32."""
+        from repro.configs import get_config
+
+        cfg = dataclasses.replace(get_config("smollm-135m").reduced(),
+                                  remat=True, attn_impl="naive")
+        params = init_params(jax.random.key(0), cfg)
+        toks = jax.random.randint(jax.random.key(1), (2, 128), 0, cfg.vocab_size)
+        batch = {"tokens": toks, "labels": jnp.roll(toks, -1, axis=1)}
+
+        def loss_grad(c):
+            return jax.jit(jax.value_and_grad(
+                lambda p: forward_train(p, batch, c)[0]))(params)
+
+        loss, grads = loss_grad(cfg)
+        loss_k, grads_k = loss_grad(dataclasses.replace(cfg, attn_impl="pallas"))
+        assert abs(float(loss_k) - float(loss)) < 1e-3 * float(loss)
+        norms = [float(jnp.linalg.norm(g)) for g in jax.tree_util.tree_leaves(grads)]
+        floor = float(np.median(norms))
+        for g, gk, n in zip(jax.tree_util.tree_leaves(grads),
+                            jax.tree_util.tree_leaves(grads_k), norms):
+            assert float(jnp.linalg.norm(gk - g)) < 2e-2 * max(n, floor)
 
 
 class TestRGLRU:
