@@ -15,7 +15,6 @@ benchmarks/results/*.csv.
   cluster      — localhost 3-host socket sweep vs the process tier
   vmap         — beyond-paper: stacked-vmap trial execution vs serial
   kernels      — pure-jnp oracle timings (TPU kernel baselines)
-  roofline     — per-(arch x shape x mesh) table from the dry-run artifacts
 """
 from __future__ import annotations
 
@@ -29,13 +28,13 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="run a single bench (loc|convergence|overhead|"
                          "scaling|async|process|elastic|faults|cluster|vmap|"
-                         "kernels|roofline)")
+                         "kernels)")
     args = ap.parse_args()
 
     from . import (bench_async, bench_cluster, bench_convergence,
                    bench_elastic, bench_faults, bench_kernels, bench_loc,
-                   bench_overhead, bench_process, bench_roofline,
-                   bench_scaling, bench_vmap)
+                   bench_overhead, bench_process, bench_scaling,
+                   bench_vmap)
     benches = {
         "loc": bench_loc.run,
         "convergence": bench_convergence.run,
@@ -48,7 +47,6 @@ def main() -> None:
         "cluster": bench_cluster.run,
         "vmap": bench_vmap.run,
         "kernels": bench_kernels.run,
-        "roofline": bench_roofline.run,
     }
     selected = {args.only: benches[args.only]} if args.only else benches
 

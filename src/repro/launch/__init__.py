@@ -1,6 +1,6 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve/tune drivers,
-roofline analysis and §Perf hillclimb variants.
+"""Launchers: production mesh, multi-pod dry-run, train/serve/tune drivers
+and roofline analysis.
 
-NOTE: ``dryrun`` and ``perf`` set ``XLA_FLAGS`` for 512 placeholder devices at
-import time — import them only in dedicated processes, never from tests.
+NOTE: ``dryrun`` sets ``XLA_FLAGS`` for 512 placeholder devices at import
+time — import it only in a dedicated process, never from tests.
 """

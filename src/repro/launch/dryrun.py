@@ -17,7 +17,6 @@ os.environ["XLA_FLAGS"] = (
 ).strip()
 
 import argparse
-import dataclasses
 import json
 import time
 import traceback
@@ -63,15 +62,9 @@ def active_param_count(cfg: ModelConfig) -> int:
 def lower_one(
     arch: str, shape: ShapeSpec, mesh, mesh_name: str,
     verbose: bool = True, compile_: bool = True,
-    strategy: str = "fsdp_tp", seq_parallel: bool = False,
-    cfg_overrides: Optional[Dict[str, Any]] = None,
-    variant: str = "",
 ) -> Optional[Dict[str, Any]]:
-    """Lower+compile one combo.  ``strategy``/``seq_parallel``/``cfg_overrides``
-    parameterize §Perf variants; ``variant`` labels the record."""
+    """Lower+compile one combo under the fsdp_tp sharding strategy."""
     cfg = dryrun_config(get_config(arch))
-    if cfg_overrides:
-        cfg = dataclasses.replace(cfg, **cfg_overrides)
     reason = skip_reason(cfg, shape)
     if reason is not None:
         if verbose:
@@ -83,21 +76,20 @@ def lower_one(
     specs = input_specs(cfg, shape)
     t0 = time.time()
     from ..dist.sharding import activation_policy, sharding_strategy
-    strat_ctx = sharding_strategy(strategy)
+    strat_ctx = sharding_strategy("fsdp_tp")
     strat_ctx.__enter__()
-    policy_ctx = activation_policy(mesh, seq_parallel=seq_parallel)
+    policy_ctx = activation_policy(mesh)
     policy_ctx.__enter__()
 
     if shape.kind == "train":
         # The Tune trial's step: the optimizer's scalars are an argument.
         hypers = optimizer_hypers("adamw", 10_000, {"lr": 3e-4, "warmup": 100})
-        opt = make_optimizer("adamw", hypers, moment_dtype=cfg.opt_moment_dtype)
+        opt = make_optimizer("adamw", hypers)
         state_shapes = jax.eval_shape(
             partial(make_train_state, jax.random.key(0), cfg, opt))
         state_sh = make_shardings(train_state_specs(state_shapes, mesh, cfg), mesh)
         batch_sh = make_shardings(batch_specs(specs["batch"], mesh), mesh)
-        step = make_hyper_train_step(cfg, "adamw", microbatch=cfg.train_microbatch,
-                                     moment_dtype=cfg.opt_moment_dtype)
+        step = make_hyper_train_step(cfg, "adamw", microbatch=cfg.train_microbatch)
         jitted = jax.jit(step, in_shardings=(state_sh, batch_sh, None),
                          out_shardings=(state_sh, None), donate_argnums=(0,))
         hyper_shapes = {k: jax.ShapeDtypeStruct((), jnp.float32) for k in hypers}
@@ -133,7 +125,6 @@ def lower_one(
     record: Dict[str, Any] = {
         "arch": arch, "shape": shape.name, "mesh": mesh_name, "chips": int(chips),
         "status": "lowered", "t_lower_s": round(t_lower, 2),
-        "variant": variant or "baseline",
     }
     if not compile_:
         if verbose:

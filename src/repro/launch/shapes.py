@@ -55,14 +55,14 @@ def applicable(cfg: ModelConfig, shape: ShapeSpec) -> bool:
 
 
 def dryrun_config(cfg: ModelConfig) -> ModelConfig:
-    """bf16 params/activations, chunked attention, per-layer remat.
+    """bf16 params/activations, per-layer remat.
 
     remat=True for every arch at production sequence lengths: per-layer
     activation checkpointing is the standard 4k-training memory policy (the
     §Perf log quantifies its compute-vs-memory trade)."""
     return dataclasses.replace(
         cfg, param_dtype="bfloat16", activation_dtype="bfloat16",
-        attn_impl="auto", remat=True)
+        remat=True)
 
 
 def _sds(shape, dtype) -> jax.ShapeDtypeStruct:

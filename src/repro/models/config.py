@@ -67,11 +67,6 @@ class ModelConfig:
     remat: bool = False                   # per-layer activation checkpointing
     train_microbatch: int = 1             # gradient-accumulation slices per step
     padded_vocab: Optional[int] = None    # pad embed/head to a shardable size
-    opt_moment_dtype: str = "float32"     # AdamW m/v dtype (bf16 halves opt state)
-    attn_impl: str = "auto"               # auto | naive | chunked | pallas
-    attn_chunk: int = 512                 # q-block for chunked attention
-    kernel_impl: str = "jnp"              # jnp | pallas: RWKV6/RG-LRU scan path
-    scan_layers: bool = True              # lax.scan over (stacked) layer params
     source: str = ""                      # citation (paper / model card)
 
     # -- derived -----------------------------------------------------------------

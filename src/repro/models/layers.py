@@ -1,6 +1,13 @@
-"""Core layers: norms, RoPE, GQA/MQA attention (naive + chunked online-softmax,
-or the Pallas flash kernel of kernels/ops.py: ``attn_impl``, see
-``attn_impl()``), gated MLPs, embeddings.
+"""Core layers: norms, RoPE, GQA/MQA attention, gated MLPs, embeddings.
+
+Attention takes one of three paths, and ``attention_path`` alone chooses it
+from what the call can observe: the platform, whether an activation policy
+spreads the step over several devices, and the query and key lengths.  On a
+TPU every call with more than one query takes the Pallas flash kernel
+(kernels/flash_attention.py, forward and backward), whose S x S scores live
+only in VMEM.  A step that XLA partitions stays on jnp, since XLA cannot
+partition a Mosaic kernel; so do decode (one query) and every other
+platform: naive attention up to 2048 keys, chunked online-softmax above.
 
 Parameter containers are plain nested dicts so they stack cleanly for
 scan-over-layers and shard via path-based rules (dist/sharding.py).
@@ -133,6 +140,9 @@ def _sdpa(q, k, v, bias, softcap: Optional[float]) -> jax.Array:
     return out.reshape(B, Sq, H, hd)
 
 
+_ATTN_CHUNK = 512  # query block of the chunked path
+
+
 def _sdpa_chunked(q, k, v, q_pos, k_pos, causal, window, softcap, chunk: int) -> jax.Array:
     """Online-softmax over q-chunks: memory O(Sq_blk * Sk), never (Sq, Sk) full.
 
@@ -183,38 +193,31 @@ def project_qkv(p: Params, x: jax.Array, cfg: ModelConfig,
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
-def attn_impl(impl: str, sq: int, sk: int) -> str:
-    """The attention path a call takes: ``naive``, ``chunked`` or ``flash``.
+def attention_path(sq: int, sk: int) -> str:
+    """The attention path of a call with ``sq`` queries over ``sk`` keys:
+    ``naive``, ``chunked`` or ``flash``.
 
-    ``auto`` picks from the platform and the shapes: decode (one query)
-    stays naive; on a TPU, a step XLA does not partition takes the Pallas
-    flash kernel, whose S x S scores live only in VMEM, forward and
-    backward; elsewhere the jnp paths (naive up to 2048 keys, chunked
-    above).  An explicit choice is honoured; ``pallas`` and ``chunked`` fall
-    back to naive for one query."""
-    if impl == "auto":
-        from ..dist.sharding import activations_partitioned
-        if sq > 1 and jax.default_backend() == "tpu" and not activations_partitioned():
-            return "flash"
-        impl = "chunked" if sk > 2048 else "naive"
-    if impl not in ("naive", "chunked", "pallas"):
-        raise ValueError(f"unknown attn_impl {impl!r}")
-    if sq == 1 or impl == "naive":
+    One query (decode) stays naive.  On a TPU, a step that XLA does not
+    partition takes the flash kernel; under an activation policy over
+    several devices XLA partitions the step, and it cannot partition a
+    Mosaic kernel, so the step stays on jnp.  The jnp paths: naive up to
+    2048 keys, chunked above, where a naive (Sq, Sk) score tensor would
+    crowd HBM."""
+    from ..dist.sharding import activations_partitioned
+    if sq == 1:
         return "naive"
-    return "flash" if impl == "pallas" else "chunked"
+    if jax.default_backend() == "tpu" and not activations_partitioned():
+        return "flash"
+    return "chunked" if sk > 2048 else "naive"
 
 
 def attend(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
            q_pos: jax.Array, k_pos: jax.Array, cfg: ModelConfig,
-           causal: bool = True, window: Optional[int] = None,
-           impl: Optional[str] = None) -> jax.Array:
-    """Scaled-dot-product attention core with mask from positions.  The
-    path taken is counted, at trace time, as ``attention.impl.<path>`` in
-    ``repro.obs.PROCESS_METRICS``; its ops carry the scope
+           causal: bool = True, window: Optional[int] = None) -> jax.Array:
+    """Scaled-dot-product attention core with mask from positions, on the
+    path ``attention_path`` chooses; its ops carry the scope
     ``attention.<path>``."""
-    from ..obs import PROCESS_METRICS
-    path = attn_impl(impl or cfg.attn_impl, q.shape[1], k_all.shape[1])
-    PROCESS_METRICS.counter(f"attention.impl.{path}").inc()
+    path = attention_path(q.shape[1], k_all.shape[1])
     with jax.named_scope(f"attention.{path}"):
         if path == "flash":
             from ..kernels import ops as kops
@@ -224,7 +227,7 @@ def attend(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
                                         precision=cfg.matmul_precision)
         if path == "chunked":
             return _sdpa_chunked(q, k_all, v_all, q_pos, k_pos, causal, window,
-                                 cfg.logit_softcap, cfg.attn_chunk)
+                                 cfg.logit_softcap, _ATTN_CHUNK)
         bias = _mask_bias(q_pos, k_pos, causal, window)
         return _sdpa(q, k_all, v_all, bias, cfg.logit_softcap)
 
@@ -236,12 +239,11 @@ def attention(
     positions: jax.Array,
     causal: bool = True,
     window: Optional[int] = None,
-    impl: Optional[str] = None,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """Self-attention layer (no cache).  Returns (out, (k, v)) — this call's
     post-RoPE keys/values so callers can fill decode caches (prefill)."""
     q, k_new, v_new = project_qkv(p, x, cfg, positions)
-    out = attend(q, k_new, v_new, positions, positions, cfg, causal, window, impl)
+    out = attend(q, k_new, v_new, positions, positions, cfg, causal, window)
     B, S_, H, hd = out.shape
     y = out.reshape(B, S_, H * hd) @ p["wo"]
     return y, (k_new, v_new)

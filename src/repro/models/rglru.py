@@ -11,8 +11,10 @@ RG-LRU:  r_t = sigmoid(x W_a + b_a), i_t = sigmoid(x W_i + b_i)
 
 Training evaluates the linear recurrence with ``jax.lax.associative_scan``
 (log-depth — the TPU-native choice for a 4k..512k sequence); decode is the
-single step.  Griffin's block-diagonal gate matrices are implemented dense
-(adaptation noted in DESIGN.md — dense is MXU-friendlier at these widths).
+single step.  The Pallas scan (kernels/rglru_scan.py) has no backward, so
+the model does not call it.  Griffin's block-diagonal gate matrices are
+implemented dense (adaptation noted in DESIGN.md — dense is MXU-friendlier at
+these widths).
 """
 from __future__ import annotations
 
@@ -107,10 +109,6 @@ def apply_rglru_block(
         h_prev = h0 if h0 is not None else jnp.zeros_like(gated_in[:, 0])
         h_last = a[:, 0] * h_prev + gated_in[:, 0]
         h = h_last[:, None]
-    elif cfg.kernel_impl == "pallas":
-        from ..kernels import ops as kops
-        h = kops.rglru_scan(a, gated_in, h0)
-        h_last = h[:, -1]
     else:
         h = _rglru_scan(a, gated_in, h0)
         h_last = h[:, -1]

@@ -9,7 +9,8 @@ Training uses an exact *chunked* evaluation: within a chunk, intra-chunk
 contributions are a masked matmul with decay-ratio weights computed in log
 space (ratios are always <= 1, so no overflow); inter-chunk state is carried by
 ``lax.scan``.  Decode is the plain single-step recurrence.  The Pallas kernel
-(kernels/rwkv6_scan.py) implements the same chunked scheme with VMEM tiling.
+(kernels/rwkv6_scan.py) implements the same chunked scheme with VMEM tiling;
+it has no backward yet, so the model always takes the jnp scan.
 
 Channel-mixing: squared-ReLU MLP with static token-shift (Finch eq. 20-22).
 
@@ -191,9 +192,6 @@ def apply_time_mix(
             if S == 1:
                 y, wkv = _wkv_step(r[:, 0], k[:, 0], v[:, 0], logw[:, 0], p["u"], wkv0)
                 y = y[:, None]
-            elif cfg.kernel_impl == "pallas":
-                from ..kernels import ops as kops
-                y, wkv = kops.rwkv6_scan(r, k, v, logw, p["u"], wkv0, chunk=chunk)
             else:
                 y, wkv = _wkv_chunked(r, k, v, logw, p["u"], wkv0, chunk)
 

@@ -26,12 +26,12 @@ from typing import Any, Dict, Optional
 
 from .analysis import ExperimentAnalysis, TrialRecord
 from .flightrec import FlightRecorder, SearchStateSnapshotter, json_safe
-from .metrics import PROCESS_METRICS, Counter, Gauge, Histogram, MetricsRegistry
+from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 from .tracing import NULL_TRACER, Span, Tracer, span
 
 __all__ = ["Observability", "NULL_OBS",
            "Tracer", "Span", "NULL_TRACER", "span",
-           "MetricsRegistry", "PROCESS_METRICS", "Counter", "Gauge", "Histogram",
+           "MetricsRegistry", "Counter", "Gauge", "Histogram",
            "ExperimentAnalysis", "TrialRecord",
            "FlightRecorder", "SearchStateSnapshotter", "json_safe"]
 

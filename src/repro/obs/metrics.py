@@ -25,7 +25,7 @@ import math
 import threading
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry", "PROCESS_METRICS"]
+__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry"]
 
 
 class Counter:
@@ -187,9 +187,3 @@ class MetricsRegistry:
         return json.dumps({"t": t, "schema_version": schema_version,
                            "metrics": self.snapshot()},
                           sort_keys=True, separators=(",", ":"))
-
-
-# Counts taken where no Observability is threaded through: model code counts
-# here at trace time (``attention.impl.<name>``: each attention call traced,
-# by the implementation it took).
-PROCESS_METRICS = MetricsRegistry()

@@ -185,8 +185,7 @@ def optimizer_hypers(family: str, total_steps: int,
     return {k: float(v) for k, v in scalars.items() if v is not None}
 
 
-def make_optimizer(family: str, hypers: Dict[str, Any],
-                   moment_dtype: Any = jnp.float32) -> Optimizer:
+def make_optimizer(family: str, hypers: Dict[str, Any]) -> Optimizer:
     """The optimizer of ``family`` over the scalars of ``hypers`` (floats, or
     float32 arrays traced by the step that calls this): a linear-warmup
     cosine schedule, clipping iff ``hypers`` holds ``grad_clip``."""
@@ -195,8 +194,7 @@ def make_optimizer(family: str, hypers: Dict[str, Any],
     if family == "adamw":
         return adamw(schedule, b1=hypers["b1"], b2=hypers["b2"],
                      weight_decay=hypers["weight_decay"],
-                     grad_clip=hypers.get("grad_clip"),
-                     moment_dtype=moment_dtype)
+                     grad_clip=hypers.get("grad_clip"))
     if family == "sgd":
         return sgd(schedule, momentum=hypers["momentum"],
                    weight_decay=hypers["weight_decay"],

@@ -100,8 +100,7 @@ def make_eval_step(cfg: ModelConfig):
 
 
 def make_hyper_train_step(cfg: ModelConfig, family: str = "adamw",
-                          microbatch: int = 0, moment_dtype: Any = jnp.float32,
-                          factory: Optional[Callable] = None):
+                          microbatch: int = 0, factory: Optional[Callable] = None):
     """Returns train_step(state, batch, hypers) -> (state, metrics).
 
     The optimizer of ``family`` is built inside the trace from ``hypers``, a
@@ -113,7 +112,7 @@ def make_hyper_train_step(cfg: ModelConfig, family: str = "adamw",
 
     def train_step(state: TrainState, batch: Dict[str, jax.Array],
                    hypers: Dict[str, jax.Array]) -> Tuple[TrainState, Dict]:
-        opt = make_optimizer(family, hypers, moment_dtype)
+        opt = make_optimizer(family, hypers)
         return factory(cfg, opt, microbatch)(state, batch)
 
     return train_step

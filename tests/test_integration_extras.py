@@ -8,8 +8,9 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import ops as kops
 from repro.models import (ModelConfig, MoEConfig, forward_encode, forward_train,
-                          init_params)
+                          init_params, layers, rglru, rwkv6)
 from repro.models.moe import apply_moe_layer, init_moe_layer
 from repro.train import adamw, make_train_state, make_train_step
 
@@ -24,35 +25,43 @@ def lm_cfg(**kw):
 
 
 class TestPallasModelPaths:
-    """Models with kernel_impl='pallas' / attn_impl='pallas' (interpret mode)
-    must match the jnp paths — kernels as a real system layer."""
+    """Each Pallas kernel (interpret mode), put in the place of the jnp path
+    it stands for, must leave the model's outputs as they were: kernels as a
+    real system layer, checked at the models' shapes."""
 
-    def _compare(self, cfg_jnp, cfg_pl, atol=2e-3):
-        params = init_params(jax.random.key(0), cfg_jnp)
+    def _compare(self, cfg, monkeypatch, module, name, kernel, atol=2e-3):
+        params = init_params(jax.random.key(0), cfg)
         toks = jax.random.randint(jax.random.key(1), (2, 64), 0, V)
-        a = forward_encode(params, {"tokens": toks}, cfg_jnp)
-        b = forward_encode(params, {"tokens": toks}, cfg_pl)
+        a = forward_encode(params, {"tokens": toks}, cfg)
+        monkeypatch.setattr(module, name, kernel)
+        b = forward_encode(params, {"tokens": toks}, cfg)
         np.testing.assert_allclose(a, b, atol=atol)
 
     # At "highest" both paths compute in float32; at the default the kernel
     # rounds its MXU operands to bfloat16, as a TPU does (tests/test_models.py).
-    def test_flash_attention_in_model(self):
-        cfg = lm_cfg(attn_impl="naive", matmul_precision="highest")
-        self._compare(cfg, dataclasses.replace(cfg, attn_impl="pallas"))
+    def test_flash_attention_in_model(self, monkeypatch):
+        cfg = lm_cfg(matmul_precision="highest")
+        self._compare(cfg, monkeypatch, layers, "attention_path",
+                      lambda sq, sk: "flash")
 
-    def test_flash_attention_swa_in_model(self):
-        cfg = lm_cfg(attn_impl="naive", sliding_window=16, matmul_precision="highest")
-        self._compare(cfg, dataclasses.replace(cfg, attn_impl="pallas"))
+    def test_flash_attention_swa_in_model(self, monkeypatch):
+        cfg = lm_cfg(sliding_window=16, matmul_precision="highest")
+        self._compare(cfg, monkeypatch, layers, "attention_path",
+                      lambda sq, sk: "flash")
 
-    def test_rwkv6_pallas_scan_in_model(self):
+    def test_rwkv6_pallas_scan_in_model(self, monkeypatch):
+        """``kops.rwkv6_scan`` against ``rwkv6._wkv_chunked``."""
         cfg = lm_cfg(family="ssm", n_heads=2, rwkv_head_dim=32)
-        self._compare(cfg, dataclasses.replace(cfg, kernel_impl="pallas"))
+        self._compare(cfg, monkeypatch, rwkv6, "_wkv_chunked",
+                      lambda r, k, v, logw, u, state, chunk:
+                      kops.rwkv6_scan(r, k, v, logw, u, state, chunk=chunk))
 
-    def test_rglru_pallas_scan_in_model(self):
+    def test_rglru_pallas_scan_in_model(self, monkeypatch):
+        """``kops.rglru_scan`` against ``rglru._rglru_scan``."""
         cfg = lm_cfg(family="hybrid", n_layers=3, n_kv_heads=1,
                      block_pattern=("rglru", "rglru", "local_attn"),
                      sliding_window=16, rglru_d_rnn=64)
-        self._compare(cfg, dataclasses.replace(cfg, kernel_impl="pallas"))
+        self._compare(cfg, monkeypatch, rglru, "_rglru_scan", kops.rglru_scan)
 
 
 class TestPaddedVocab:
